@@ -2,8 +2,8 @@
 engine, across wave sizes K ∈ {1, 4, 16, 64}.
 
 Two tiers per K:
-  * kernel tier — K × ``gather_rows`` pallas_calls vs ONE ``checkout_batched``
-    pallas_call (interpret mode off-TPU; on TPU the gap is the K-1 saved
+  * kernel tier — K × ``checkout_gather`` gathers vs ONE ``checkout_batched``
+    gather (interpret mode off-TPU; on TPU the gap is the K-1 saved
     pipeline spin-ups plus the fused DMA stream);
   * host tier — K separate ``data[rl]`` takes vs one take over the
     concatenated rlists (the numpy fallback the serve layer uses off-device).

@@ -140,8 +140,9 @@ class SynchronousServer:
         finally:
             _cb.plan_batched = vec
         packed = K.checkout_wave(sb.device(), wp.plan.starts, wp.plan.mode,
-                                 wp.hi, block_n=sb.block_n, block_d=sb.bd)
-        packed = np.asarray(packed)[:, :sb.d]
+                                 wp.hi, block_n=sb.block_n,
+                                 row_lanes=sb.row_lanes)
+        packed = np.asarray(packed).reshape(-1, sb.host.shape[1])[:, :sb.d]
         return [packed[wp.segment(k, sb.block_n)] for k in range(len(uniq))]
 
     def flush(self):

@@ -501,6 +501,8 @@ class _WavePart:
                                         # the launch rode _wave_launcher)
     segments: Optional[list] = None     # per-idx row slices of ``packed``
     d: int = 0                          # valid feature width of ``packed``
+    width: int = 0                      # padded row width: ``packed`` holds
+                                        # lane-rows, width/128 per row
 
     def split(self) -> list:
         """Force this part to host blocks: join the in-flight launch, ONE
@@ -513,7 +515,7 @@ class _WavePart:
             packed = self.packed
             if isinstance(packed, concurrent.futures.Future):
                 packed = packed.result()
-            arr = np.asarray(packed)[:, :self.d]
+            arr = np.asarray(packed).reshape(-1, self.width)[:, :self.d]
             self.mats = [arr[seg] for seg in self.segments]
             self.packed = None          # release the device handle
             self.segments = None
@@ -590,9 +592,11 @@ class Superblock:
     Layout: partition p owns rows [row_offsets[p], row_offsets[p] + R_p) of
     ``host``; each segment is padded to a BLOCK_N multiple (``bounds[p]`` is
     the aligned exclusive end — the safe upper limit for a run DMA landing
-    in p), and D is padded to the lane-tile multiple so the kernel consumes
-    the array as-is.  ``device()`` uploads once and pins the copy; the
-    epoch captured at build keys cache invalidation.
+    in p), and D is padded to the lane-tile multiple.  ``device()`` uploads
+    once and pins the copy in the kernels' lane-row layout — the row-major
+    view ``(R_pad * row_lanes, 128)`` of ``host`` (``kernels.plan_launch``)
+    — so the kernels consume it as-is; the epoch captured at build keys
+    cache invalidation.
 
     A whole-store superblock covers every partition (``pids`` is None and
     segment i belongs to partition i); a PARTITION-GROUP superblock covers
@@ -608,6 +612,7 @@ class Superblock:
     epoch: int                # store.epoch at build time
     _device: object = dataclasses.field(default=None, repr=False)
     uploads: int = 0          # host→device transfers performed
+    launches: int = 0         # kernel gathers run off this superblock
     cache_key: object = None  # the get_superblock args this is cached under
     pids: Optional[np.ndarray] = None   # group members (None = all partitions)
     _slot_of: Optional[dict] = dataclasses.field(default=None, repr=False)
@@ -621,6 +626,12 @@ class Superblock:
     def n_rows(self) -> int:
         return self.host.shape[0]
 
+    @property
+    def row_lanes(self) -> int:
+        """Lane-rows per superblock row in the device layout."""
+        from ..kernels.plan_launch import LANES
+        return self.host.shape[1] // LANES
+
     def slot(self, pid: int) -> int:
         """Segment index of partition ``pid`` in this superblock — the pid
         itself for a whole-store superblock, the group-local position for a
@@ -632,11 +643,13 @@ class Superblock:
         return self._slot_of.get(int(pid), -1)
 
     def device(self):
-        """The device-resident copy — uploaded on first use, then pinned."""
+        """The device-resident lane-row copy — uploaded on first use, then
+        pinned."""
         if self._device is None:
             fault_point("superblock.upload")
             import jax.numpy as jnp
-            self._device = jnp.asarray(self.host)
+            from ..kernels.plan_launch import LANES
+            self._device = jnp.asarray(self.host.reshape(-1, LANES))
             self.uploads += 1
         return self._device
 
@@ -647,10 +660,12 @@ def _superblock_layout(parts, block_n: Optional[int], block_d: Optional[int]):
     ``estimate_superblock_bytes`` and ``migrate_superblock`` so all three
     agree byte-for-byte."""
     from ..kernels.checkout_gather import DEFAULT_BD, DEFAULT_BN
+    from ..kernels.plan_launch import LANES
     bn = DEFAULT_BN if block_n is None else block_n
     blk_d = DEFAULT_BD if block_d is None else block_d
     d = max((p.block.shape[1] for p in parts), default=0)
-    bd = min(blk_d, max(128, d)) if d else blk_d
+    # the lane tile is a multiple of 128 lanes, so the padded width is too
+    bd = min(blk_d, -(-d // LANES) * LANES) if d else blk_d
     d_pad = -(-max(d, 1) // bd) * bd
     seg = np.array([-(-p.block.shape[0] // bn) * bn for p in parts], np.int64)
     row_offsets = np.concatenate([[0], np.cumsum(seg)[:-1]]).astype(np.int64) \
@@ -955,6 +970,12 @@ class SuperblockGroups:
         self.groups_touched = 0
         self.straggler_requests = 0
         self.auto_regroups = 0      # heat-drift regroups maybe_regroup fired
+        self.migrated = 0           # groups migrated in place (segment_move)
+        self.extended = 0           # groups grown in place by ingest waves
+        self.lazy_rebuilds = 0      # group migrations that fell back to a
+                                    # rebuild on next touch
+        self.append_evictions = 0   # ingest extensions that fell back to
+                                    # eviction + rebuild on next touch
         self.last_wave: Optional[GroupWaveReport] = None
         self._plan_epoch = -1
         # heat-drift auto-regroup knobs (see maybe_regroup): every
@@ -1299,9 +1320,11 @@ def migrate_groups(store, plan, taken: Sequence[Superblock], *,
                                                install=False)
             except ValueError:      # tiling changed: rebuild on next touch
                 old_sb._device = None
+                mgr.lazy_rebuilds += 1
                 continue
             except Exception:       # transient (injected/allocator): this
                 old_sb._device = None   # group rebuilds lazily, rest proceed
+                mgr.lazy_rebuilds += 1
                 logger.warning("group migration failed; falling back to "
                                "lazy rebuild", exc_info=True)
                 continue
@@ -1309,6 +1332,7 @@ def migrate_groups(store, plan, taken: Sequence[Superblock], *,
             if mgr.install(new_sb, protected=kept):
                 kept.add(tuple(int(q) for q in np.asarray(new_sb.pids)))
                 migrated += 1
+                mgr.migrated += 1
         try:
             mgr.plan_groups()       # regroup leftovers around the survivors
         except Exception:
@@ -1657,14 +1681,15 @@ def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock, *,
     if defer and _defer_via_worker(wp.n_tiles):
         packed = _wave_launcher().submit(
             K.checkout_wave, dev, wp.plan.starts, wp.plan.mode, wp.hi,
-            block_n=sb.block_n, block_d=sb.bd)
+            block_n=sb.block_n, row_lanes=sb.row_lanes)
     else:
         packed = K.checkout_wave(dev, wp.plan.starts, wp.plan.mode, wp.hi,
-                                 block_n=sb.block_n, block_d=sb.bd)
+                                 block_n=sb.block_n, row_lanes=sb.row_lanes)
+    sb.launches += 1
     return _WavePart(idxs=idxs, packed=packed,
                      segments=[wp.segment(k, sb.block_n)
                                for k in range(len(gvids))],
-                     d=sb.d), True, dt
+                     d=sb.d, width=sb.host.shape[1]), True, dt
 
 
 def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
@@ -1909,14 +1934,16 @@ def migrate_superblock(store, old_sb: Superblock, plan, *,
     if used_device:
         import jax.numpy as jnp
         from ..kernels import ops as K
+        from ..kernels.plan_launch import LANES
         if delta is None:       # all tiles reused: the kernel still needs a
             # delta operand, but a device-side fill uploads nothing
-            delta_dev = jnp.zeros((bn, d_pad), dtype=dtype)
+            delta_dev = jnp.zeros((bn * new_sb.row_lanes, LANES), dtype=dtype)
         else:
-            delta_dev = jnp.asarray(delta)
+            delta_dev = jnp.asarray(delta.reshape(-1, LANES))
             bytes_uploaded = delta.nbytes
         new_sb._device = K.segment_move(old_sb._device, delta_dev,
-                                        sel, starts, block_n=bn, block_d=bd)
+                                        sel, starts, block_n=bn,
+                                        row_lanes=new_sb.row_lanes)
         new_sb.uploads = 1 if bytes_uploaded else 0
 
     if install and pids is None:
@@ -2065,14 +2092,15 @@ def extend_superblock_after_commit(store, old_sb: Superblock,
     if used_device:
         import jax.numpy as jnp
         from ..kernels import ops as K
+        from ..kernels.plan_launch import LANES
         if delta is None:
-            delta_dev = jnp.zeros((bn, d_pad), dtype=dtype)
+            delta_dev = jnp.zeros((bn * new_sb.row_lanes, LANES), dtype=dtype)
         else:
-            delta_dev = jnp.asarray(delta)
+            delta_dev = jnp.asarray(delta.reshape(-1, LANES))
             bytes_uploaded = delta.nbytes
         new_sb._device = K.segment_append(old_sb._device, delta_dev,
-                                          sel, starts,
-                                          block_n=bn, block_d=bd)
+                                          sel, starts, block_n=bn,
+                                          row_lanes=new_sb.row_lanes)
         new_sb.uploads = 1 if bytes_uploaded else 0
     stats = MigrationStats(
         n_tiles=n_tiles, reused_tiles=reused, delta_tiles=n_delta,
@@ -2147,8 +2175,13 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
     mgr = getattr(store, "_superblock_groups", None)
     if mgr is None:
         return report
+    # only the groups this wave grows are protected: a touched group's
+    # growth LRU-evicts COLD groups to fit the budget, never another
+    # touched group (protecting every pinned group made a full budget
+    # evict the grown group itself)
     kept: set[tuple] = set(
-        k for k, sb in mgr.groups.items() if sb.epoch == epoch - 1)
+        k for k, sb in mgr.groups.items()
+        if sb.epoch == epoch - 1 and set(k) & touched)
     for key in list(mgr.groups):
         sb = mgr.groups.get(key)
         if sb is None:          # a _make_room below already evicted it
@@ -2184,6 +2217,7 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
             if key in mgr.groups:
                 mgr._evict(key)
             report["evicted"] += 1
+            mgr.append_evictions += 1
             logger.warning("in-place group superblock append failed; "
                            "group rebuilds lazily on next touch",
                            exc_info=True)
@@ -2195,6 +2229,7 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
         mgr.group_bytes[key] = int(new_sb.host.nbytes)
         mgr.pinned_bytes += int(new_sb.host.nbytes) - int(sb.host.nbytes)
         sb._device = None
+        mgr.extended += 1
         report["extended"] += 1
         report["bytes_uploaded"] += st.bytes_uploaded
         report["delta_tiles"] += st.delta_tiles

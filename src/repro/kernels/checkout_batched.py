@@ -20,10 +20,12 @@ Data flow::
                       (the row-gather path)
            emits (starts, mode, tile_offsets): a flat tile plan whose
            concatenation covers every requested version back to back
-      └─ checkout_batched                   [device, ONE pallas_call]
-           grid = (total_tiles, D/BD); the plan rides in scalar-prefetch
-           (SMEM) so the DMA engine sees every source address ahead of the
-           body — the K-version wave streams as one pipeline
+      └─ checkout_wave                      [device, pallas_call]
+           grid = (total_tiles,); the plan rides in scalar-prefetch (SMEM)
+           so the DMA engine sees every source address ahead of the body —
+           the K-version wave streams as one pipeline (split into a few
+           launches into one output when the plan outgrows SMEM; see
+           ``plan_launch``)
       └─ split per version                  [host, zero-copy slices]
            out[k] = packed[tile_offsets[k]*BN : tile_offsets[k]*BN + n_k]
 
@@ -54,7 +56,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .checkout_gather import DEFAULT_BD, DEFAULT_BN
+from .checkout_gather import DEFAULT_BN
+from .plan_launch import planned_tile_call
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,14 +175,15 @@ def plan_batched_loop(rlists, block_n: int = DEFAULT_BN,
                        n_rows=n_rows, density=density)
 
 
-def _make_wave_kernel(block_n: int, block_d: int):
-    """Like ``_make_kernel`` but with a per-tile row bound: run DMAs fire
-    only when the whole (BN, BD) read stays inside the tile's partition
-    segment of the superblock (``hi`` is the exclusive bound)."""
+def _make_wave_kernel(block_n: int, row_lanes: int):
+    """The wave gather body over lane-row arrays: a logical row is
+    ``row_lanes`` consecutive lane-rows.  Run DMAs fire only when the whole
+    BN-row read stays inside the tile's partition segment of the
+    superblock (``hi`` is the exclusive bound, in logical rows)."""
+    w = row_lanes
+
     def kernel(starts_ref, mode_ref, hi_ref, data_ref, o_ref, sems):
         t = pl.program_id(0)
-        j = pl.program_id(1)
-        col = pl.ds(j * block_d, block_d)
         s0 = starts_ref[t * block_n]
         run_ok = jnp.logical_and(mode_ref[t] == 1,
                                  s0 + block_n <= hi_ref[t])
@@ -187,77 +191,48 @@ def _make_wave_kernel(block_n: int, block_d: int):
         @pl.when(run_ok)
         def _run():
             cp = pltpu.make_async_copy(
-                data_ref.at[pl.ds(s0, block_n), col], o_ref, sems.at[0])
+                data_ref.at[pl.ds(s0 * w, block_n * w)], o_ref, sems.at[0])
             cp.start()
             cp.wait()
 
         @pl.when(jnp.logical_not(run_ok))
         def _rows():
+            def row(i):
+                return pltpu.make_async_copy(
+                    data_ref.at[pl.ds(starts_ref[t * block_n + i] * w, w)],
+                    o_ref.at[pl.ds(i * w, w)], sems.at[i])
             for i in range(block_n):
-                pltpu.make_async_copy(
-                    data_ref.at[pl.ds(starts_ref[t * block_n + i], 1), col],
-                    o_ref.at[pl.ds(i, 1)], sems.at[i]).start()
+                row(i).start()
             for i in range(block_n):
-                pltpu.make_async_copy(
-                    data_ref.at[pl.ds(starts_ref[t * block_n + i], 1), col],
-                    o_ref.at[pl.ds(i, 1)], sems.at[i]).wait()
+                row(i).wait()
 
     return kernel
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "row_lanes",
+                                             "interpret", "max_tiles"))
 def checkout_wave(data: jax.Array, starts: jax.Array, mode: jax.Array,
-                  hi: jax.Array, *,
-                  block_n: int = DEFAULT_BN, block_d: int = DEFAULT_BD,
-                  interpret: bool = False) -> jax.Array:
-    """Execute a cross-partition ``plan_wave`` plan: ONE pallas_call for a
-    wave spanning any number of partitions.
+                  hi: jax.Array, *, block_n: int = DEFAULT_BN,
+                  row_lanes: int = 1, interpret: bool = False,
+                  max_tiles: int | None = None) -> jax.Array:
+    """Execute a cross-partition ``plan_wave`` plan over a lane-row
+    superblock, in as few launches as SMEM allows (see ``plan_launch``).
 
-    data:   (R, D) superblock — every partition's rows concatenated, D a
-            multiple of block_d (pad at superblock build).
+    data:   (R*row_lanes, 128) lane-row superblock — every partition's
+            rows concatenated, each logical row ``row_lanes`` lane-rows.
     starts: (T*block_n,) int32 superblock rids (rebased by partition offset).
     mode:   (T,) int32 per-tile gather mode (1 = run candidate).
     hi:     (T,) int32 per-tile exclusive row bound for run DMAs.
-    Returns (T*block_n, D) packed rows; slice per version with the plan's
-    segments.
+    max_tiles: optional cap on tiles per launch (below the SMEM bound).
+    Returns (T*block_n*row_lanes, 128) packed lane-rows: logical row i is
+    lane-rows [i*row_lanes, (i+1)*row_lanes); slice per version with the
+    plan's segments after the host reshape to (T*block_n, row_lanes*128).
     """
-    r, d = data.shape
     t = mode.shape[0]
-    bd = min(block_d, d)
-    assert d % bd == 0, (d, bd)
-    grid = (t, d // bd)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((block_n, bd), lambda i, j, s, m, h: (i, j)),
+    return planned_tile_call(
+        _make_wave_kernel(block_n, row_lanes),
+        [starts.astype(jnp.int32), mode.astype(jnp.int32),
+         hi.astype(jnp.int32)], [data],
+        n_tiles=t, block_rows=block_n * row_lanes, dtype=data.dtype,
         scratch_shapes=[pltpu.SemaphoreType.DMA((block_n,))],
-    )
-    return pl.pallas_call(
-        _make_wave_kernel(block_n, bd), grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((t * block_n, d), data.dtype),
-        interpret=interpret,
-    )(starts.astype(jnp.int32), mode.astype(jnp.int32),
-      hi.astype(jnp.int32), data)
-
-
-def checkout_batched(data: jax.Array, starts: jax.Array, mode: jax.Array, *,
-                     block_n: int = DEFAULT_BN, block_d: int = DEFAULT_BD,
-                     interpret: bool = False) -> jax.Array:
-    """Execute a ``plan_batched`` plan: ONE pallas_call for the whole wave.
-
-    The single-block special case of ``checkout_wave``: ``plan_batched``
-    only marks exactly-consecutive chunks as runs, so every run DMA is
-    in-bounds by construction and the per-tile bound degenerates to the
-    block's row count.
-
-    data:   (R, D) with D a multiple of block_d (pad upstream).
-    starts: (T*block_n,) int32 source rids (plan.starts).
-    mode:   (T,) int32 per-tile gather mode (plan.mode).
-    Returns (T*block_n, D) packed rows; slice per version with plan.segment.
-    """
-    hi = jnp.full(mode.shape, data.shape[0], jnp.int32)
-    return checkout_wave(data, starts, mode, hi,
-                         block_n=block_n, block_d=block_d,
-                         interpret=interpret)
+        interpret=interpret, max_tiles=max_tiles)

@@ -6,30 +6,21 @@ data block.  On Postgres this is a hash join whose cost is linear in the
 partition size (App. D.1); on TPU it is an HBM->VMEM row gather whose cost is
 linear in bytes touched — same cost model, different constant.
 
-Two kernels:
+``gather_row_tiles`` — beyond-paper optimization: rlists are SORTED, so after
+LYRESPLIT partitioning a checkout touches long dense runs of the block.
+``plan_tiles`` RLEs the rlist into BN-row-aligned tile indices and each grid
+step DMAs a (BN, BD) tile.  Checkout has SET semantics (a version is a set
+of records), so the packed tile output needs no reordering; the planner's
+``perm`` exists for oracle comparison.  The feature dimension is tiled at BD
+(a multiple of 128 lanes) so the VMEM working set stays bounded regardless
+of table width.
 
-* ``gather_rows``        — scalar-prefetch gather: the rlist lives in SMEM and
-                           drives the data BlockSpec's index_map, so each grid
-                           step DMAs exactly one (1, BD) row tile.  This is the
-                           canonical TPU gather (indices known before the body
-                           runs => the DMA engine can pipeline ahead).
-* ``gather_row_tiles``   — beyond-paper optimization: rlists are SORTED, so
-                           after LYRESPLIT partitioning a checkout touches
-                           long dense runs of the block.  ``plan_tiles`` RLEs
-                           the rlist into BN-row-aligned tile indices and each
-                           grid step DMAs a (BN, BD) tile — up to BN× fewer,
-                           BN× larger DMAs for the same bytes.  Checkout has
-                           SET semantics (a version is a set of records), so
-                           the packed tile output needs no reordering; the
-                           planner's ``perm`` exists for oracle comparison.
-
-Both tile the feature dimension at BD (lane-width multiple of 128) so the
-VMEM working set stays bounded regardless of table width.
+A per-row gather in request order (``kernels.ops.checkout_gather``) is the
+all-row-DMA case of the wave kernel in ``checkout_batched``.
 
 Multi-version retrieval (K versions, one launch) lives in the sibling module
-``checkout_batched`` — it fuses both modes above into a single adaptive
-(starts, mode) plan executed by ONE pallas_call; see its module docstring for
-the engine data-flow map.
+``checkout_batched`` — it fuses run and row gathers into a single adaptive
+(starts, mode) plan; see its module docstring for the engine data-flow map.
 """
 from __future__ import annotations
 
@@ -50,32 +41,6 @@ def _copy_kernel(idx_ref, x_ref, o_ref):
     # x_ref is the row tile selected by the index_map; copy through.
     del idx_ref
     o_ref[...] = x_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def gather_rows(data: jax.Array, rids: jax.Array, *, block_d: int = DEFAULT_BD,
-                interpret: bool = False) -> jax.Array:
-    """out[i, :] = data[rids[i], :] via scalar-prefetch row gather.
-
-    data: (R, D) — D must be a multiple of the feature tile (pad upstream).
-    rids: (N,) int32.
-    """
-    r, d = data.shape
-    n = rids.shape[0]
-    bd = min(block_d, d)
-    assert d % bd == 0, (d, bd)
-    grid = (n, d // bd)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bd), lambda i, j, idx: (idx[i], j))],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j, idx: (i, j)),
-    )
-    return pl.pallas_call(
-        _copy_kernel, grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), data.dtype),
-        interpret=interpret,
-    )(rids.astype(jnp.int32), data)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))

@@ -20,6 +20,7 @@ from . import segment_append as _sa
 from . import segment_move as _sm
 from . import version_agg as _va
 from . import vlist_membership as _vm
+from .plan_launch import LANES
 
 
 def _on_tpu() -> bool:
@@ -36,20 +37,49 @@ def _pad_axis(x: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def checkout_gather(data, rids, *, block_d: int = _cg.DEFAULT_BD,
+def _lane_rows(data, min_rows: int = 1) -> tuple[jax.Array, int]:
+    """(device lane-row array, row_lanes) of a logical (R, D) row array:
+    columns zero-padded to a multiple of 128 lanes, rows to at least
+    ``min_rows``, then viewed as (R * row_lanes, 128) — free for host
+    (numpy) input, which is then uploaded once; see ``plan_launch``."""
+    xp = jnp if isinstance(data, jax.Array) else np
+    data = xp.asarray(data)
+    r, d = data.shape
+    d_pad = -(-max(d, 1) // LANES) * LANES
+    if d_pad != d or r < min_rows:
+        data = xp.pad(data, ((0, max(min_rows - r, 0)), (0, d_pad - d)))
+    return jnp.asarray(data.reshape(-1, LANES)), d_pad // LANES
+
+
+def _check_lane_rows(arr, what: str) -> None:
+    if arr.ndim != 2 or arr.shape[1] != LANES:
+        raise ValueError(
+            f"{what} shape {tuple(arr.shape)} is not the lane-row layout "
+            f"(rows, {LANES}) of the {LANES}-lane tile — build superblocks "
+            "with core.checkout.build_superblock (which pads and reshapes)")
+
+
+def checkout_gather(data, rids, *, block_n: int = _cg.DEFAULT_BN,
                     use_kernel: bool | None = None) -> jax.Array:
-    """Materialize a version: rows of ``data`` named by ``rids``."""
-    data = jnp.asarray(data)
-    rids = jnp.asarray(rids)
+    """Materialize a version: rows of ``data`` named by ``rids``, in the
+    order given — the all-row-DMA case of the wave kernel."""
     if use_kernel is None:
         use_kernel = True
     if not use_kernel:
-        return _ref.gather_rows_ref(data, rids)
-    d = data.shape[1]
-    bd = min(block_d, max(128, d))
-    padded = _pad_axis(data, bd, axis=1)
-    out = _cg.gather_rows(padded, rids, block_d=bd, interpret=not _on_tpu())
-    return out[:, :d]
+        return _ref.gather_rows_ref(jnp.asarray(data), jnp.asarray(rids))
+    rids = np.asarray(rids, np.int64)
+    n, d = len(rids), np.shape(data)[1]
+    if n == 0:
+        return jnp.zeros((0, d), jnp.asarray(data).dtype)
+    lane_data, w = _lane_rows(data)
+    t = -(-n // block_n)
+    starts = np.full(t * block_n, rids[-1], np.int32)
+    starts[:n] = rids
+    out = _cb.checkout_wave(
+        lane_data, jnp.asarray(starts), jnp.zeros(t, jnp.int32),
+        jnp.full(t, np.shape(data)[0], jnp.int32), block_n=block_n,
+        row_lanes=w, interpret=not _on_tpu())
+    return out.reshape(-1, w * LANES)[:n, :d]
 
 
 def _validate_rlist(rids, *, sort: bool = True) -> tuple[np.ndarray, np.ndarray | None]:
@@ -93,7 +123,7 @@ def checkout_gather_tiled(data, rids, *, block_n: int = _cg.DEFAULT_BN,
         unsorted_perm[order] = perm
         perm = unsorted_perm
     d = data.shape[1]
-    bd = min(block_d, max(128, d))
+    bd = min(block_d, -(-max(d, 1) // LANES) * LANES)
     padded = _pad_axis(_pad_axis(data, bd, axis=1), block_n, axis=0)
     out = _cg.gather_row_tiles(padded, jnp.asarray(tiles), block_n=block_n,
                                block_d=bd, interpret=not _on_tpu())
@@ -101,14 +131,15 @@ def checkout_gather_tiled(data, rids, *, block_n: int = _cg.DEFAULT_BN,
 
 
 def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
-                     block_d: int = _cg.DEFAULT_BD,
                      density_threshold: float = 0.05,
                      interpret: bool | None = None):
-    """Fused multi-version checkout: K rlists, ONE ``pallas_call``.
+    """Fused multi-version checkout: K rlists, one wave-kernel gather.
 
     Plans the concatenation of the rlists with ``plan_batched`` — per-tile
     run DMAs where the rlist is dense, row DMAs where it is scattered —
-    executes the whole wave in a single kernel launch, and splits the packed
+    executes the whole wave as ``checkout_batched.checkout_wave`` (the
+    per-tile bound degenerates to the block's row count: ``plan_batched``
+    only marks exactly-consecutive chunks as runs), and splits the packed
     output back into per-version row blocks.
 
     Row k's block is data[rlists[k]] exactly — rids are honored AS GIVEN
@@ -119,7 +150,6 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
 
     Returns (list of (n_k, D) arrays in request order, BatchedPlan).
     """
-    data = jnp.asarray(data)
     rls = []
     for rl in rlists:
         rl = np.asarray(rl)
@@ -128,94 +158,77 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
         rls.append(rl)
     plan = _cb.plan_batched(rls, block_n=block_n,
                             density_threshold=density_threshold)
-    d = data.shape[1]
+    r, d = np.shape(data)
     if plan.n_tiles == 0:
-        empty = np.zeros((0, d), dtype=data.dtype)
+        empty = np.zeros((0, d), dtype=np.asarray(data[:0]).dtype)
         return [empty for _ in rls], plan
-    bd = min(block_d, max(128, d))
-    padded = _pad_axis(data, bd, axis=1)
-    if padded.shape[0] < block_n:
-        # a block shorter than one row tile cannot even TRACE the kernel
-        # (the run-DMA dynamic_slice is statically (block_n, bd)); pad rows
-        # up to the tile — runs only fire on consecutive REAL rids, so the
-        # pad rows are never addressed
-        padded = _pad_axis(padded, block_n, axis=0)
-    packed = _cb.checkout_batched(
-        padded, jnp.asarray(plan.starts), jnp.asarray(plan.mode),
-        block_n=block_n, block_d=bd,
+    # a block shorter than one row tile cannot even TRACE the kernel (the
+    # run DMA is statically block_n rows); pad rows up to the tile — runs
+    # only fire on consecutive REAL rids, so the pad rows are never read
+    lane_data, w = _lane_rows(data, min_rows=block_n)
+    packed = _cb.checkout_wave(
+        lane_data, jnp.asarray(plan.starts), jnp.asarray(plan.mode),
+        jnp.full(plan.n_tiles, r, jnp.int32), block_n=block_n, row_lanes=w,
         interpret=not _on_tpu() if interpret is None else interpret)
-    packed = np.asarray(packed)[:, :d]
+    packed = np.asarray(packed).reshape(-1, w * LANES)[:, :d]
     return [packed[plan.segment(k, block_n)] for k in range(len(rls))], plan
 
 
 def checkout_wave(data, starts, mode, hi, *, block_n: int = _cg.DEFAULT_BN,
-                  block_d: int = _cg.DEFAULT_BD,
+                  row_lanes: int = 1,
                   interpret: bool | None = None) -> jax.Array:
-    """Cross-partition fused checkout: a whole multi-partition wave, ONE
-    ``pallas_call`` over a pre-padded superblock.
+    """Cross-partition fused checkout: a whole multi-partition wave over a
+    lane-row superblock ``(R * row_lanes, 128)`` — a few launches into one
+    output at most (``plan_launch``).
 
     Thin wrapper over ``checkout_batched.checkout_wave`` — the superblock
-    (``core.checkout.build_superblock``) is already padded to the lane tile
-    and BN-aligned per partition segment, so no padding happens here; this
-    only resolves the interpret/TPU mode and casts the plan arrays.
+    (``core.checkout.build_superblock``) is already padded and in the
+    lane-row layout, so nothing is padded here; this only resolves the
+    interpret/TPU mode.  Returns packed lane-rows: reshape on the host to
+    ``(-1, row_lanes * 128)`` for logical rows.
     """
     data = jnp.asarray(data)
-    d = data.shape[1]
-    bd = min(block_d, max(128, d))
-    if d % bd:
-        raise ValueError(
-            f"superblock D={d} not a multiple of the lane tile {bd} — build "
-            "it with core.checkout.build_superblock (which pre-pads)")
+    _check_lane_rows(data, "superblock")
     return _cb.checkout_wave(
         data, jnp.asarray(starts), jnp.asarray(mode), jnp.asarray(hi),
-        block_n=block_n, block_d=bd,
+        block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
 
 def segment_move(src, delta, sel, starts, *, block_n: int = _cg.DEFAULT_BN,
-                 block_d: int = _cg.DEFAULT_BD,
+                 row_lanes: int = 1,
                  interpret: bool | None = None) -> jax.Array:
     """Incremental superblock migration: assemble the post-migration
-    superblock in ONE ``pallas_call``, reusing BN-aligned tiles of the OLD
-    device-resident superblock (sel 0) and pulling only changed tiles from
-    a small host-uploaded delta (sel 1).  Both sources must already be
-    lane-tile padded (``core.checkout`` builds them that way)."""
+    superblock reusing BN-aligned tiles of the OLD device-resident
+    superblock (sel 0) and pulling only changed tiles from a small
+    host-uploaded delta (sel 1).  Both sources are lane-row arrays
+    (``core.checkout`` builds them that way)."""
     src = jnp.asarray(src)
     delta = jnp.asarray(delta)
-    d = src.shape[1]
-    bd = min(block_d, max(128, d))
-    if d % bd:
-        raise ValueError(
-            f"superblock D={d} not a multiple of the lane tile {bd} — "
-            "migrate via core.checkout.migrate_superblock (which pre-pads)")
+    _check_lane_rows(src, "superblock")
+    _check_lane_rows(delta, "delta")
     return _sm.segment_move(
         src, delta, jnp.asarray(sel), jnp.asarray(starts),
-        block_n=block_n, block_d=bd,
+        block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
 
 def segment_append(src, delta, sel, starts, *,
-                   block_n: int = _cg.DEFAULT_BN,
-                   block_d: int = _cg.DEFAULT_BD,
+                   block_n: int = _cg.DEFAULT_BN, row_lanes: int = 1,
                    interpret: bool | None = None) -> jax.Array:
     """In-place superblock append for a commit ingest wave: assemble the
-    grown superblock in ONE ``pallas_call``, reusing BN-aligned tiles of
-    the OLD device-resident superblock (sel 0), uploading only the new
-    BN-aligned tiles from a small host delta (sel 1), and zero-filling
-    alignment-slack tiles on device (sel 2).  Both sources must already be
-    lane-tile padded (``core.checkout`` builds them that way)."""
+    grown superblock reusing BN-aligned tiles of the OLD device-resident
+    superblock (sel 0), uploading only the new BN-aligned tiles from a
+    small host delta (sel 1), and zero-filling alignment-slack tiles on
+    device (sel 2).  Both sources are lane-row arrays (``core.checkout``
+    builds them that way)."""
     src = jnp.asarray(src)
     delta = jnp.asarray(delta)
-    d = src.shape[1]
-    bd = min(block_d, max(128, d))
-    if d % bd:
-        raise ValueError(
-            f"superblock D={d} not a multiple of the lane tile {bd} — "
-            "extend via core.checkout.refresh_superblocks_after_commit "
-            "(which pre-pads)")
+    _check_lane_rows(src, "superblock")
+    _check_lane_rows(delta, "delta")
     return _sa.segment_append(
         src, delta, jnp.asarray(sel), jnp.asarray(starts),
-        block_n=block_n, block_d=bd,
+        block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
 
@@ -223,9 +236,9 @@ def membership_scan(bitmap, vid: int, *, block_r: int = _vm.DEFAULT_BR):
     """(mask, per-block counts) for version ``vid`` over the bitset vlists."""
     bitmap = jnp.asarray(bitmap)
     r = bitmap.shape[0]
-    br = min(block_r, max(8, r))
+    br = -(-min(block_r, max(r, 1)) // LANES) * LANES
     padded = _pad_axis(bitmap, br, axis=0)
-    mask, cnt = _vm.membership_scan(padded, vid=vid, block_r=br,
+    mask, cnt = _vm.membership_scan(padded.T, vid=vid, block_r=br,
                                     interpret=not _on_tpu())
     return mask[:r], cnt
 
@@ -236,10 +249,10 @@ def version_aggregate(bitmap, values, *, block_r: int = _va.DEFAULT_BR):
     bitmap = jnp.asarray(bitmap)
     values = jnp.asarray(values)
     r = bitmap.shape[0]
-    br = min(block_r, max(8, r))
+    br = -(-min(block_r, max(r, 1)) // LANES) * LANES
     padded_bm = _pad_axis(bitmap, br, axis=0)
     padded_v = _pad_axis(values, br, axis=0)
-    return _va.version_aggregate(padded_bm, padded_v, block_r=br,
+    return _va.version_aggregate(padded_bm.T, padded_v, block_r=br,
                                  interpret=not _on_tpu())
 
 

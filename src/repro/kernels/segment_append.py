@@ -11,7 +11,7 @@ this kernel zero-fills them on device instead.
 
 Every BN-row output tile of the post-ingest superblock is produced by one
 of three per-tile selector modes (prefetched to SMEM like the rest of the
-wave-engine plans):
+wave-engine plans, one SMEM-sized launch at a time):
 
     sel[t] == 0  ->  reuse: copy rows [start[t], start[t]+BN) of the OLD
                      device-resident superblock (device-to-device; never
@@ -35,27 +35,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .checkout_gather import DEFAULT_BD, DEFAULT_BN
+from .checkout_gather import DEFAULT_BN
+from .plan_launch import planned_tile_call
 
 
-def _make_kernel(block_n: int, block_d: int):
+def _make_kernel(block_n: int, row_lanes: int):
+    rows = block_n * row_lanes
+
     def kernel(sel_ref, start_ref, src_ref, delta_ref, o_ref, sems):
         t = pl.program_id(0)
-        j = pl.program_id(1)
-        col = pl.ds(j * block_d, block_d)
-        s0 = start_ref[t]
+        s0 = start_ref[t] * row_lanes
 
         @pl.when(sel_ref[t] == 0)
         def _reuse():
             cp = pltpu.make_async_copy(
-                src_ref.at[pl.ds(s0, block_n), col], o_ref, sems.at[0])
+                src_ref.at[pl.ds(s0, rows)], o_ref, sems.at[0])
             cp.start()
             cp.wait()
 
         @pl.when(sel_ref[t] == 1)
         def _delta():
             cp = pltpu.make_async_copy(
-                delta_ref.at[pl.ds(s0, block_n), col], o_ref, sems.at[0])
+                delta_ref.at[pl.ds(s0, rows)], o_ref, sems.at[0])
             cp.start()
             cp.wait()
 
@@ -66,44 +67,35 @@ def _make_kernel(block_n: int, block_d: int):
     return kernel
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "row_lanes",
+                                             "interpret", "max_tiles"))
 def segment_append(src: jax.Array, delta: jax.Array, sel: jax.Array,
-                   starts: jax.Array, *,
-                   block_n: int = DEFAULT_BN, block_d: int = DEFAULT_BD,
-                   interpret: bool = False) -> jax.Array:
-    """Extend a superblock in place: T output tiles, ONE pallas_call.
+                   starts: jax.Array, *, block_n: int = DEFAULT_BN,
+                   row_lanes: int = 1, interpret: bool = False,
+                   max_tiles: int | None = None) -> jax.Array:
+    """Extend a superblock in place: T output tiles, in as few launches as
+    SMEM allows (see ``plan_launch``).
 
-    src:    (R_old, D) the pre-commit superblock (device-resident).
-    delta:  (R_delta, D) host-uploaded new/changed rows, BN-tile packed.
+    src:    (R_old*row_lanes, 128) the pre-commit lane-row superblock
+            (device-resident).
+    delta:  (R_delta*row_lanes, 128) host-uploaded new/changed rows,
+            BN-tile packed, same lane-row layout.
     sel:    (T,) int32 per-tile source — 0 = src, 1 = delta, 2 = zero pad.
-    starts: (T,) int32 first source row of the tile in its chosen source
-            (ignored for sel == 2).
-    Returns (T*block_n, D): the post-commit superblock.  Growth is the
-    norm: T*block_n exceeds R_old by the wave's BN-aligned new tiles.
+    starts: (T,) int32 first source row (logical) of the tile in its
+            chosen source (ignored for sel == 2).
+    Returns (T*block_n*row_lanes, 128): the post-commit superblock.
+    Growth is the norm: T*block_n exceeds R_old by the wave's BN-aligned
+    new tiles.
 
-    Both sources must share the (lane-tile padded) feature width D; every
-    sel 0/1 run [starts[t], starts[t]+block_n) must be in-bounds for its
-    source — ``core.checkout._extend_group_superblock`` guarantees both by
-    construction (runs that would cross an old aligned segment end are
-    routed to the delta; all-pad tiles never read a source).
+    Every sel 0/1 run [starts[t], starts[t]+block_n) must be in-bounds for
+    its source — ``core.checkout.extend_superblock_after_commit``
+    guarantees it by construction (runs that would cross an old aligned
+    segment end are routed to the delta; all-pad tiles never read a
+    source).
     """
-    r, d = src.shape
-    t = sel.shape[0]
-    bd = min(block_d, d)
-    assert d % bd == 0, (d, bd)
-    assert delta.shape[1] == d, (delta.shape, d)
-    grid = (t, d // bd)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((block_n, bd), lambda i, j, s, st: (i, j)),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
-    )
-    return pl.pallas_call(
-        _make_kernel(block_n, bd), grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((t * block_n, d), src.dtype),
-        interpret=interpret,
-    )(sel.astype(jnp.int32), starts.astype(jnp.int32), src, delta)
+    return planned_tile_call(
+        _make_kernel(block_n, row_lanes),
+        [sel.astype(jnp.int32), starts.astype(jnp.int32)], [src, delta],
+        n_tiles=sel.shape[0], block_rows=block_n * row_lanes,
+        dtype=src.dtype, scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        interpret=interpret, max_tiles=max_tiles)

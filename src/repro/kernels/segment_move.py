@@ -10,7 +10,8 @@ which is *already resident on device*: only rows that migration actually
 moved across partition boundaries (or freshly materialized) need to travel
 over the host→device link.
 
-This kernel executes that copy plan in ONE ``pallas_call``: every BN-row
+This kernel executes that copy plan as one ``pallas_call`` per SMEM-sized
+slice of the plan, all writing one output: every BN-row
 output tile of the new superblock is produced by a single run DMA from one
 of two sources, chosen by a prefetched per-tile selector:
 
@@ -35,69 +36,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .checkout_gather import DEFAULT_BD, DEFAULT_BN
+from .checkout_gather import DEFAULT_BN
+from .plan_launch import planned_tile_call
 
 
-def _make_kernel(block_n: int, block_d: int):
+def _make_kernel(block_n: int, row_lanes: int):
+    rows = block_n * row_lanes
+
     def kernel(sel_ref, start_ref, src_ref, delta_ref, o_ref, sems):
         t = pl.program_id(0)
-        j = pl.program_id(1)
-        col = pl.ds(j * block_d, block_d)
-        s0 = start_ref[t]
+        s0 = start_ref[t] * row_lanes
 
         @pl.when(sel_ref[t] == 0)
         def _reuse():
             cp = pltpu.make_async_copy(
-                src_ref.at[pl.ds(s0, block_n), col], o_ref, sems.at[0])
+                src_ref.at[pl.ds(s0, rows)], o_ref, sems.at[0])
             cp.start()
             cp.wait()
 
         @pl.when(sel_ref[t] != 0)
         def _delta():
             cp = pltpu.make_async_copy(
-                delta_ref.at[pl.ds(s0, block_n), col], o_ref, sems.at[0])
+                delta_ref.at[pl.ds(s0, rows)], o_ref, sems.at[0])
             cp.start()
             cp.wait()
 
     return kernel
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_n", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "row_lanes",
+                                             "interpret", "max_tiles"))
 def segment_move(src: jax.Array, delta: jax.Array, sel: jax.Array,
-                 starts: jax.Array, *,
-                 block_n: int = DEFAULT_BN, block_d: int = DEFAULT_BD,
-                 interpret: bool = False) -> jax.Array:
-    """Assemble a migrated superblock: T output tiles, ONE pallas_call.
+                 starts: jax.Array, *, block_n: int = DEFAULT_BN,
+                 row_lanes: int = 1, interpret: bool = False,
+                 max_tiles: int | None = None) -> jax.Array:
+    """Assemble a migrated superblock: T output tiles, in as few launches
+    as SMEM allows (see ``plan_launch``).
 
-    src:    (R_old, D) the pre-migration superblock (device-resident).
-    delta:  (R_delta, D) host-uploaded changed rows, BN-tile packed.
+    src:    (R_old*row_lanes, 128) the pre-migration lane-row superblock
+            (device-resident).
+    delta:  (R_delta*row_lanes, 128) host-uploaded changed rows, BN-tile
+            packed, same lane-row layout.
     sel:    (T,) int32 per-tile source — 0 = src (reuse), 1 = delta.
-    starts: (T,) int32 first source row of the tile in its chosen source.
-    Returns (T*block_n, D): the post-migration superblock.
+    starts: (T,) int32 first source row (logical) of the tile in its
+            chosen source.
+    Returns (T*block_n*row_lanes, 128): the post-migration superblock.
 
-    Both sources must share the (lane-tile padded) feature width D; every
-    run [starts[t], starts[t]+block_n) must be in-bounds for its source —
-    ``core.checkout.migrate_superblock`` guarantees both by construction
-    (tiles whose source run would cross an aligned segment end are routed
-    to the delta instead).
+    Every run [starts[t], starts[t]+block_n) must be in-bounds for its
+    source — ``core.checkout.migrate_superblock`` guarantees it by
+    construction (tiles whose source run would cross an aligned segment
+    end are routed to the delta instead).
     """
-    r, d = src.shape
-    t = sel.shape[0]
-    bd = min(block_d, d)
-    assert d % bd == 0, (d, bd)
-    assert delta.shape[1] == d, (delta.shape, d)
-    grid = (t, d // bd)
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((block_n, bd), lambda i, j, s, st: (i, j)),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
-    )
-    return pl.pallas_call(
-        _make_kernel(block_n, bd), grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((t * block_n, d), src.dtype),
-        interpret=interpret,
-    )(sel.astype(jnp.int32), starts.astype(jnp.int32), src, delta)
+    return planned_tile_call(
+        _make_kernel(block_n, row_lanes),
+        [sel.astype(jnp.int32), starts.astype(jnp.int32)], [src, delta],
+        n_tiles=sel.shape[0], block_rows=block_n * row_lanes,
+        dtype=src.dtype, scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        interpret=interpret, max_tiles=max_tiles)

@@ -9,10 +9,10 @@ aggregate over a value column is
 
 i.e. a {0,1}-matrix × vector product.  Unpacking 32 versions from one uint32
 word turns the CSR segment-sum (scatter-heavy, TPU-hostile) into a dense
-(BR, 32) × (BR,) reduction per word column — MXU/VPU-friendly, no scatters,
-sequential HBM traffic.  The grid walks (version-word, record-block) with the
-record-block axis innermost, accumulating into the output block (revisiting
-pattern: the output BlockSpec ignores the record-block index).
+(32, BR) × (BR,) reduction per word row — VPU-friendly, no scatters,
+sequential HBM traffic.  The kernel reads the TRANSPOSED bitmap (W, R), so
+records run along the 128-lane axis; the grid walks record blocks and
+accumulates into the one (W*32, 1) output block every step revisits.
 """
 from __future__ import annotations
 
@@ -22,45 +22,48 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .plan_launch import LANES
 
-DEFAULT_BR = 1024   # record rows per grid step
+DEFAULT_BR = 1024   # records per grid step (a multiple of 128 lanes)
 
 
-def _agg_kernel(bm_ref, val_ref, o_ref):
-    rb = pl.program_id(1)
-
-    @pl.when(rb == 0)
+def _agg_kernel(bm_ref, val_ref, o_ref, *, n_words: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    word = bm_ref[:, 0]                                   # (BR,) uint32
-    shifts = jnp.arange(32, dtype=jnp.uint32)             # (32,)
-    bits = (word[:, None] >> shifts[None, :]) & jnp.uint32(1)   # (BR, 32)
-    vals = val_ref[...]                                   # (BR,)
-    part = jnp.sum(bits.astype(jnp.float32) * vals[:, None], axis=0)  # (32,)
-    o_ref[...] += part
+    vals = val_ref[...]                                       # (1, BR) f32
+    br = vals.shape[1]
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (32, br), 0) \
+        .astype(jnp.uint32)
+    for k in range(n_words):
+        word = jnp.broadcast_to(bm_ref[k:k + 1, :], (32, br))  # (32, BR)
+        bits = ((word >> shifts) & jnp.uint32(1)).astype(jnp.int32) \
+            .astype(jnp.float32)
+        part = jnp.sum(bits * vals, axis=1, keepdims=True)     # (32, 1)
+        o_ref[k * 32:(k + 1) * 32, :] += part
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def version_aggregate(bitmap: jax.Array, values: jax.Array, *,
+def version_aggregate(bitmap_t: jax.Array, values: jax.Array, *,
                       block_r: int = DEFAULT_BR, interpret: bool = False
                       ) -> jax.Array:
     """out: (W*32,) float32 — per-version sums of ``values`` (masked upstream
     for predicates; use values=1.0 for COUNT).
 
-    bitmap: (R, W) uint32; values: (R,) float32; R multiple of block_r.
+    bitmap_t: (W, R) uint32 — the TRANSPOSED bitset; values: (R,) float32;
+    R a multiple of block_r, block_r a multiple of 128.
     """
-    r, w = bitmap.shape
+    w, r = bitmap_t.shape
     br = min(block_r, r)
-    assert r % br == 0, (r, br)
-    grid = (w, r // br)   # record-block axis innermost => accumulation works
+    assert r % br == 0 and br % LANES == 0, (r, br)
     out = pl.pallas_call(
-        _agg_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((br, 1), lambda vw, rb: (rb, vw)),
-                  pl.BlockSpec((br,), lambda vw, rb: (rb,))],
-        out_specs=pl.BlockSpec((32,), lambda vw, rb: (vw,)),
-        out_shape=jax.ShapeDtypeStruct((w * 32,), jnp.float32),
+        functools.partial(_agg_kernel, n_words=w),
+        grid=(r // br,),
+        in_specs=[pl.BlockSpec((w, br), lambda rb: (0, rb)),
+                  pl.BlockSpec((1, br), lambda rb: (0, rb))],
+        out_specs=pl.BlockSpec((w * 32, 1), lambda rb: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((w * 32, 1), jnp.float32),
         interpret=interpret,
-    )(bitmap, values.astype(jnp.float32))
-    return out
+    )(bitmap_t, values.astype(jnp.float32).reshape(1, r))
+    return out[:, 0]

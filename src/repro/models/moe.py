@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding import compat_shard_map
 from .layers import Params, dense_abstract, dense_init, swiglu_abstract, swiglu_init
 
 
@@ -88,8 +87,7 @@ def _dispatch_combine(x, router_w, wi, wg, wo, *, cfg: MoEConfig, model_axis: st
     """Runs PER (pod,data)-SHARD inside shard_map.  x: (T_loc, d)."""
     t_loc, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
-    m = (jax.lax.axis_size(model_axis) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, model_axis))
+    m = jax.lax.axis_size(model_axis)
     e_loc = e // m
     c = _capacity(t_loc, cfg)
 
@@ -154,7 +152,7 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig, mesh: jax.sharding.Mesh,
                               cfg=cfg, model_axis=model_axis)
         return y.reshape(xs.shape)
 
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(dp_axes, seq_spec, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -20,28 +20,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def compat_shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-                     axis_names=None, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    older releases only have ``jax.experimental.shard_map.shard_map`` with
-    ``auto``/``check_rep``.  ``axis_names`` = the MANUAL axes (all mesh axes
-    when None), which maps to ``auto = mesh.axis_names - axis_names``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names) \
-        if axis_names is not None else frozenset()
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshContext:
     mesh: Mesh

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models.transformer import ArchConfig, loss_fn, param_specs
-from ..sharding import MeshContext, compat_shard_map, dp_spec, mesh_context, shard
+from ..sharding import MeshContext, dp_spec, mesh_context, shard
 from .optimizer import AdamW, AdamWState
 
 
@@ -163,7 +163,7 @@ def make_train_step(cfg: ArchConfig, ctx: MeshContext, opt: Optional[AdamW] = No
         loss_avg = jax.lax.pmean(loss, "pod")
         return loss_avg, grads_hat, new_ef
 
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         per_pod, mesh=mesh,
         in_specs=(P(), P("pod"), P("pod")),
         out_specs=(P(), P(), P("pod")),

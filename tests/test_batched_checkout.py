@@ -64,13 +64,37 @@ def test_checkout_batched_single_pallas_call(rng, monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(_cb.pl, "pallas_call", counting)
-    # unusual dims so no earlier test populated this jit cache entry
+    _cb.checkout_wave.clear_cache()    # force a fresh trace: count is exact
     data = rng.integers(0, 100, (611, 23)).astype(np.int32)
     rls = _random_rlists(rng, 611, 16)
     outs, _ = ops.checkout_batched(data, rls, interpret=True)
     for got, want in zip(outs, ref.gather_batched_ref(data, rls)):
         np.testing.assert_array_equal(np.asarray(got), want)
     assert sum(calls) == 1
+
+
+@pytest.mark.parametrize("row_lanes", [1, 2])
+@pytest.mark.parametrize("max_tiles", [1, 3, 7])
+def test_checkout_wave_split_launches_bit_identical(rng, row_lanes,
+                                                   max_tiles):
+    """A plan too big for one launch's SMEM runs as several launches into
+    one aligned output: bit-identical to a single launch and to the oracle,
+    for one- and two-lane-row (D=128/256) superblocks."""
+    r, d = 300, 128 * row_lanes
+    data = rng.integers(0, 1000, (r, d)).astype(np.int32)
+    rls = _random_rlists(rng, r, 6)
+    plan = _cb.plan_batched(rls)
+    args = (jnp.asarray(data.reshape(-1, 128)), jnp.asarray(plan.starts),
+            jnp.asarray(plan.mode), jnp.full(plan.n_tiles, r, jnp.int32))
+    one = np.asarray(_cb.checkout_wave(*args, row_lanes=row_lanes,
+                                       interpret=True))
+    split = np.asarray(_cb.checkout_wave(*args, row_lanes=row_lanes,
+                                         interpret=True,
+                                         max_tiles=max_tiles))
+    np.testing.assert_array_equal(split, one)
+    packed = split.reshape(-1, d)
+    for k, want in enumerate(ref.gather_batched_ref(data, rls)):
+        np.testing.assert_array_equal(packed[plan.segment(k, 8)], want)
 
 
 def test_plan_batched_modes(rng):
@@ -85,7 +109,7 @@ def test_plan_batched_modes(rng):
 
 
 def test_single_version_kernels_vs_oracle(rng):
-    """gather_rows / gather_row_tiles interpret=True vs the jnp oracle
+    """checkout_gather / gather_row_tiles interpret=True vs the jnp oracle
     (the per-version building blocks the batched engine replaces)."""
     r, d = 512, 64
     data = rng.integers(0, 1000, (r, d)).astype(np.int32)

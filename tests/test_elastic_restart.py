@@ -3,6 +3,7 @@ checkpoint CVD, and elastically restore onto a different mesh shape —
 verifying bit-exact state round-trips and replay-free data cursors."""
 import dataclasses
 import os
+import pathlib
 
 import jax
 import numpy as np
@@ -10,11 +11,14 @@ import pytest
 
 from repro.core import generate, lyresplit_for_budget, to_tree
 from repro.data import VersionedDataset
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.transformer import ArchConfig, param_specs
 from repro.sharding import logical_to_sharding, make_ctx
 from repro.train import AdamW, CheckpointStore, make_train_step
 from repro.train.ft import resume_latest
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 TINY = ArchConfig(name="tiny-ft", family="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv=2, d_ff=128, vocab=256, head_dim=16,
@@ -44,7 +48,7 @@ def _run(steps, start, params, state, step_fn, ds, vid):
 
 def test_restart_resumes_exact_step_and_data(tmp_path):
     ds, vid = _dataset()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = make_ctx(mesh)
     opt = AdamW(lr=1e-3)
     step_fn = jax.jit(make_train_step(TINY, ctx, opt))
@@ -78,11 +82,6 @@ def test_restart_resumes_exact_step_and_data(tmp_path):
         np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) <= (0, 4)
-    and jax.default_backend() == "cpu",
-    reason="known env failure on jax 0.4.x CPU: the forced-2-device restore "
-    "compile in the fresh subprocess exceeds the 300s timeout")
 def test_elastic_restore_across_mesh_shapes(tmp_path):
     """Save from a (1,1) mesh, restore onto (2,1) and (1,2) meshes — the
     checkpoint stores logical specs, so any device count works."""
@@ -92,6 +91,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
             import os
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.models import init_params
             from repro.models.transformer import param_specs
             from repro.sharding import logical_to_sharding
@@ -102,7 +102,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
             vid = store.save(step=1, tree=p, meta={"cursor": 1})
             for shape, names in [((2, 1), ("data", "model")),
                                  ((1, 2), ("data", "model"))]:
-                mesh = jax.make_mesh(shape, names)
+                mesh = make_mesh(shape, names)
                 q = store.restore(vid, mesh=mesh, specs=param_specs(TINY),
                                   treedef_like=p)
                 for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(q)):
@@ -112,8 +112,9 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         """ % str(tmp_path / "cvd2"))
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, timeout=300,
-                           env={"PYTHONPATH": "src:.", "HOME": "/root",
-                                "PATH": "/usr/bin:/bin"}, cwd="/root/repo")
+                           env={"PYTHONPATH": "src:.",
+                                "HOME": os.environ.get("HOME", str(REPO)),
+                                "PATH": "/usr/bin:/bin"}, cwd=str(REPO))
         assert "ELASTIC_OK" in r.stdout, r.stderr[-2000:]
     else:
         pytest.skip("covered by subprocess variant")

@@ -174,6 +174,7 @@ def test_single_fused_pallas_call_per_group(rng, monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(_cb.pl, "pallas_call", spy)
+    _cb.checkout_wave.clear_cache()    # force a fresh trace: count is exact
     # partitions 0 (three vids) and 1 (one vid): the two groups' plan
     # shapes differ, so each launch is a fresh trace (same-shape launches
     # would share one compiled trace and hide the second pallas_call)

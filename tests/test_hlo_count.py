@@ -1,24 +1,18 @@
 """The trip-count-aware HLO analyzer (launch/hlo_count.py): scan == unroll,
 fused dots counted, collectives counted through loops (subprocess with forced
 device count)."""
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
 
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.launch.hlo_count import analyze, parse_hlo
 
-# Known environment failures on the jax 0.4.x CPU toolchain (see CHANGES.md):
-# sharded-program compiles in fresh subprocesses exceed the 300s timeout, and
-# CPU FloatNormalization rewrites bf16 dots into f32 converts that the
-# effective-width byte model intentionally does not mimic.  Both are
-# CPU-specific, so the skip requires version AND platform — a TPU on jax
-# 0.4.x still runs the full coverage.
-_JAX_04X_CPU = (tuple(int(x) for x in jax.__version__.split(".")[:2]) <= (0, 4)
-                and jax.default_backend() == "cpu")
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _compiled_text(fn, *args):
@@ -98,9 +92,6 @@ def test_parse_handles_tuple_shapes_and_comments():
     assert a.flops == 2 * 4 * 4 * 4
 
 
-@pytest.mark.skipif(
-    _JAX_04X_CPU, reason="known env failure on jax 0.4.x CPU: the sharded-scan "
-    "compile in the fresh subprocess exceeds the 300s timeout")
 def test_collectives_through_scan_subprocess():
     """Needs >1 device: run in a subprocess with forced host device count."""
     code = textwrap.dedent("""
@@ -109,11 +100,8 @@ def test_collectives_through_scan_subprocess():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_count import analyze
-        try:
-            from jax.sharding import AxisType
-            mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
-        except ImportError:
-            mesh = jax.make_mesh((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         def f(x, w):
             def body(c, wi):
                 y = c @ wi
@@ -136,14 +124,11 @@ def test_collectives_through_scan_subprocess():
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"},
-                       cwd="/root/repo")
+                            "HOME": os.environ.get("HOME", str(REPO))},
+                       cwd=str(REPO))
     assert "OK" in r.stdout, r.stderr[-2000:]
 
 
-@pytest.mark.skipif(
-    _JAX_04X_CPU, reason="known env failure on jax 0.4.x CPU: FloatNormalization "
-    "emits extra f32 converts the byte model counts (720896 vs 458752)")
 def test_bf16_dot_not_inflated():
     """CPU FloatNormalization wraps bf16 dots in f32 converts; the effective-
     width model must count TPU-native bf16 traffic (operands + result at

@@ -9,6 +9,7 @@ replay at EVERY kill boundary), the trigger-resync and mid-rebuild
 regressions, and the serve-layer write tickets (single-server and
 multi-tenant)."""
 import contextlib
+import functools
 import os
 
 import numpy as np
@@ -234,13 +235,37 @@ def test_segment_append_kernel_modes():
     #        reuse0  delta0  pad   reuse3  delta2  pad
     sel = np.array([0, 1, 2, 0, 1, 2], np.int32)
     starts = np.array([0, 0, 0, 3 * bn, 2 * bn, 0], np.int32)
-    out = np.asarray(K.segment_append(src, delta, sel, starts,
-                                      block_n=bn, interpret=True))
+    # lane-row layout: a 256-wide row is two consecutive 128-lane rows
+    out = np.asarray(K.segment_append(src.reshape(-1, 128),
+                                      delta.reshape(-1, 128), sel, starts,
+                                      block_n=bn, row_lanes=d // 128,
+                                      interpret=True)).reshape(-1, d)
     expect = np.concatenate([
         src[:bn], delta[:bn], np.zeros((bn, d), np.float32),
         src[3 * bn:4 * bn], delta[2 * bn:3 * bn],
         np.zeros((bn, d), np.float32)])
     np.testing.assert_array_equal(out, expect)
+
+
+@pytest.mark.parametrize("kernel", ["segment_append", "segment_move"])
+@pytest.mark.parametrize("max_tiles", [1, 4])
+def test_segment_kernels_split_launches_bit_identical(kernel, max_tiles):
+    """Plans bigger than one launch's SMEM run as several launches into one
+    output — bit-identical to a single launch."""
+    import importlib
+    mod = importlib.import_module(f"repro.kernels.{kernel}")
+    rng = np.random.default_rng(3)
+    bn, t = 8, 11
+    src = rng.integers(0, 1 << 20, (6 * bn, 128)).astype(np.int32)
+    delta = rng.integers(0, 1 << 20, (4 * bn, 128)).astype(np.int32)
+    sel = rng.integers(0, 3 if kernel == "segment_append" else 2,
+                       t).astype(np.int32)
+    starts = np.where(sel == 0, rng.integers(0, 5 * bn, t),
+                      rng.integers(0, 3 * bn, t)).astype(np.int32)
+    run = functools.partial(getattr(mod, kernel), src, delta, sel, starts,
+                            block_n=bn, interpret=True)
+    np.testing.assert_array_equal(np.asarray(run(max_tiles=max_tiles)),
+                                  np.asarray(run()))
 
 
 def test_segment_append_rejects_ragged_width():
