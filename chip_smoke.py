@@ -20,8 +20,10 @@ through ``serve.BatchedCheckoutServer`` on the kernel path:
 Every delivered block is compared with a plain host reference of the same
 semantics (``data[rlist(v)]``, with its own numpy appends for the commits).
 A phase fails the run unless the counters show that the kernels did the
-work: no degraded or retried wave, no lazy rebuild, no append eviction, and
-exactly one upload per pinned superblock.
+work: no degraded or retried wave, no lazy rebuild, no append eviction,
+exactly one upload per pinned superblock, group superblocks grown or
+migrated on the device with only their delta uploaded, and read-backs
+served by group kernel launches.
 
 Usage: ``python chip_smoke.py [--seed N]``.  Needs a TPU: on any other
 backend it exits non-zero before doing any work.  The last line of stdout
@@ -124,6 +126,20 @@ def _check_clean(srv, phase: str) -> None:
     _check(st.degraded_waves == 0 and st.retries == 0 and st.requeues == 0,
            f"{phase}: degraded={st.degraded_waves} retries={st.retries} "
            f"requeues={st.requeues}")
+
+
+def _check_device_built(sbs, counted: int, phase: str, verb: str) -> None:
+    """The group superblocks ``sbs`` were built by a device kernel from
+    their old device copies (the manager counted every one of them), and
+    only their delta crossed the host link."""
+    _check(len(sbs) > 0 and counted == len(sbs),
+           f"{phase}: {counted} groups {verb} on the device, "
+           f"{len(sbs)} new group superblocks pinned")
+    for g in sbs:
+        _check(g._device is not None and g.uploads <= 1
+               and g.bytes_uploaded < g.host.nbytes,
+               f"{phase}: group {tuple(g.pids)} uploads={g.uploads} "
+               f"bytes_uploaded={g.bytes_uploaded} of {g.host.nbytes}")
 
 
 def _make_commits(rng, ref: HostReference, parents, k: int, inserts: int,
@@ -254,15 +270,21 @@ def run(cfg: SmokeConfig, journal_dir: Path, emit=print) -> dict:
     commits = _make_commits(rng, ref, parents, cfg.commits, cfg.inserts,
                             cfg.n_attrs)
     ext0, ev0 = mgr.extended, mgr.append_evictions
+    before = dict(mgr.groups)
     with BatchedCheckoutServer(store, use_kernel=True) as srv:
         wt = srv.submit_commit(commits)
         srv.flush()
         new_vids = [int(srv.result(t)) for t in wt]
         _check(new_vids == list(range(n_versions, n_versions + cfg.commits)),
                f"(c): commit_many assigned vids {new_vids}")
+        grown = [g for k, g in mgr.groups.items() if before.get(k) is not g]
+        _check_device_built(grown, mgr.extended - ext0, "(c)", "extended")
         n = serve_stream(srv, [new_vids], ref)
         _check_clean(srv, "(c)")
         st = srv.stats
+    _check(st.group_launches > 0 and st.straggler_requests == 0,
+           f"(c): read-back group_launches={st.group_launches} "
+           f"stragglers={st.straggler_requests}")
     recs, bad = read_records(journal.path)
     batches = [r for r in recs if r.kind == "commit.batch"]
     _check(bad is None and len(batches) == 1
@@ -276,7 +298,10 @@ def run(cfg: SmokeConfig, journal_dir: Path, emit=print) -> dict:
     emit(f"phase c (ingest, K={cfg.commits}): commit_waves=1 "
          f"journal_batches=1 fsyncs={journal.synced} "
          f"groups_extended={mgr.extended - ext0} append_evictions=0 "
-         f"read_back_blocks={n} bit_identical=yes "
+         f"bytes_uploaded={sum(g.bytes_uploaded for g in grown)} "
+         f"of_group_bytes={sum(g.host.nbytes for g in grown)} "
+         f"read_back_blocks={n} group_launches={st.group_launches} "
+         f"bit_identical=yes "
          f"wall_s={time.perf_counter() - t0:.3f} (host clock, compiles "
          f"included) {tag}")
     summary["c"] = n
@@ -290,20 +315,28 @@ def run(cfg: SmokeConfig, journal_dir: Path, emit=print) -> dict:
     store.apply_migration(plan)
     _check(mgr.lazy_rebuilds == lr0,
            "(d): a group migration fell back to a lazy rebuild")
-    _check(mgr.migrated > mig0, "(d): no group was migrated in place")
     migrated = mgr.migrated - mig0
+    # the only groups pinned right after the migration are the migrated ones
+    moved = list(mgr.groups.values())
+    _check_device_built(moved, migrated, "(d)", "migrated")
     newest_all = np.arange(len(ref.rlists) - 1,
                            len(ref.rlists) - 1 - cfg.hot, -1)
     with BatchedCheckoutServer(store, use_kernel=True) as srv:
         n = serve_stream(srv, zipf_waves(rng, newest_all, cfg.waves,
                                          cfg.zipf_a), ref)
         _check_clean(srv, "(d)")
+        st = srv.stats
+    _check(st.group_launches > 0,
+           f"(d): read-back group_launches={st.group_launches}")
     attach_journal(store, None)
     journal.close()
     emit(f"phase d (migration): partitions={len(store.partitions)} "
          f"groups_migrated={migrated} lazy_rebuilds=0 "
          f"rows_moved={plan.rows_moved} rows_loaded={plan.rows_loaded} "
-         f"read_back_blocks={n} bit_identical=yes "
+         f"bytes_uploaded={sum(g.bytes_uploaded for g in moved)} "
+         f"of_group_bytes={sum(g.host.nbytes for g in moved)} "
+         f"read_back_blocks={n} group_launches={st.group_launches} "
+         f"bit_identical=yes "
          f"wall_s={time.perf_counter() - t0:.3f} (host clock, compiles "
          f"included) {tag}")
     summary["d"] = n

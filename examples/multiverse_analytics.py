@@ -39,7 +39,7 @@ def main():
     # Q3: which versions contain a specific record (membership kernel)
     target_rid = int(w.graph.rlist(10)[0])
     mask, _ = ops.membership_scan(bm, vid=10)
-    vlist_of_record = np.flatnonzero(bm[target_rid])   # word-level, then bits
+    vlist_of_record = np.flatnonzero(bm[:, target_rid])  # word-level, then bits
     print(f"Q3 record r{target_rid}: member of version 10? "
           f"{bool(np.asarray(mask)[target_rid])}")
 

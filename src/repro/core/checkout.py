@@ -5,7 +5,7 @@ Data-flow map (kernels -> core -> query/serve)::
     request: vids = [v0, v1, ... v_{K-1}]          (query layer, serve layer)
       └─ superblock                                core.checkout (this module)
       │    get_superblock concatenates every partition's block into ONE
-      │    (ΣR_p, D) array (segments BN-aligned, D padded to the lane tile),
+      │    (ΣR_p, D) array (segments BN-aligned, D padded to 128 lanes),
       │    cached on the store keyed by ``store.epoch`` — repeated waves
       │    reuse the device-resident copy and skip the host→device transfer
       └─ plan_wave                                 [host, vectorized numpy]
@@ -15,8 +15,10 @@ Data-flow map (kernels -> core -> query/serve)::
       │    per-tile ``hi`` bound (partition segment end) that lets
       │    consecutive tail chunks promote to run DMAs
       └─ one fused gather for the WHOLE wave
-      │    device path:  kernels.ops.checkout_wave — ONE pallas_call no
-      │                  matter how many partitions the wave touches (run
+      │    device path:  kernels.ops.checkout_wave — one pallas_call per
+      │                  SMEM-sized slice of the plan (``kernels.plan_launch``;
+      │                  usually one), all writing one output, no matter
+      │                  how many partitions the wave touches (run
       │                  DMAs where the rlist is dense, row DMAs where
       │                  scattered; the ``hi`` bound is checked on device)
       │    host path:    one np.take over the rebased concatenation when a
@@ -58,10 +60,12 @@ this module)::
       └─ refresh_superblocks_after_commit            (this module)
            targeted device-state maintenance instead of the old
            nuke-every-superblock: pinned groups the wave did NOT touch
-           revalidate at the new epoch in place (zero work, stay pinned);
+           revalidate at the new epoch in place (zero work; they stay
+           pinned unless a grown group needs their bytes);
            touched superblocks extend IN PLACE via
-           ``extend_superblock_after_commit`` — ONE
-           ``kernels.ops.segment_append`` pallas_call reuses every
+           ``extend_superblock_after_commit`` — the
+           ``kernels.ops.segment_append`` kernel (one pallas_call per
+           SMEM-sized slice of the tile plan) reuses every
            untouched BN-aligned tile device-to-device (sel 0), uploads
            only the new tiles (sel 1) and zero-fills alignment slack on
            device (sel 2), so an ingest wave's host→device traffic is
@@ -88,8 +92,9 @@ paper §4.3)::
       │    the move source, base data only for genuinely new rows), bumps
       │    the epoch and EAGERLY evicts the stale superblock cache
       └─ migrate_superblock(store, old_sb, plan)   [device, incremental]
-           rebuilds the superblock as ONE ``kernels.ops.segment_move``
-           pallas_call: untouched BN-aligned tiles are device-to-device
+           rebuilds the superblock with ``kernels.ops.segment_move`` (one
+           pallas_call per SMEM-sized slice of the tile plan, all writing
+           one output): untouched BN-aligned tiles are device-to-device
            copies from the OLD superblock (never re-crossing the host link);
            only changed tiles ride a small host-uploaded delta — the
            intelligent-migration analogue of Figs 14-15, applied to the
@@ -592,7 +597,7 @@ class Superblock:
     Layout: partition p owns rows [row_offsets[p], row_offsets[p] + R_p) of
     ``host``; each segment is padded to a BLOCK_N multiple (``bounds[p]`` is
     the aligned exclusive end — the safe upper limit for a run DMA landing
-    in p), and D is padded to the lane-tile multiple.  ``device()`` uploads
+    in p), and D is padded to a multiple of 128 lanes.  ``device()`` uploads
     once and pins the copy in the kernels' lane-row layout — the row-major
     view ``(R_pad * row_lanes, 128)`` of ``host`` (``kernels.plan_launch``)
     — so the kernels consume it as-is; the epoch captured at build keys
@@ -607,11 +612,11 @@ class Superblock:
     row_offsets: np.ndarray   # (P,) int64 — first superblock row of segment p
     bounds: np.ndarray        # (P,) int64 — aligned exclusive end of segment p
     d: int                    # original feature width (pre-padding)
-    bd: int                   # lane-tile width the feature axis is padded to
     block_n: int              # row alignment of the partition segments
     epoch: int                # store.epoch at build time
     _device: object = dataclasses.field(default=None, repr=False)
     uploads: int = 0          # host→device transfers performed
+    bytes_uploaded: int = 0   # host→device bytes those transfers moved
     launches: int = 0         # kernel gathers run off this superblock
     cache_key: object = None  # the get_superblock args this is cached under
     pids: Optional[np.ndarray] = None   # group members (None = all partitions)
@@ -651,29 +656,28 @@ class Superblock:
             from ..kernels.plan_launch import LANES
             self._device = jnp.asarray(self.host.reshape(-1, LANES))
             self.uploads += 1
+            self.bytes_uploaded += int(self.host.nbytes)
         return self._device
 
 
-def _superblock_layout(parts, block_n: Optional[int], block_d: Optional[int]):
-    """The (row_offsets, bounds, d, bd, d_pad, total_rows, dtype) layout a
-    superblock over ``parts`` would have — shared by ``build_superblock``,
+def _superblock_layout(parts, block_n: Optional[int]):
+    """The (block_n, row_offsets, bounds, d, d_pad, total_rows, dtype) layout
+    a superblock over ``parts`` would have — shared by ``build_superblock``,
     ``estimate_superblock_bytes`` and ``migrate_superblock`` so all three
-    agree byte-for-byte."""
-    from ..kernels.checkout_gather import DEFAULT_BD, DEFAULT_BN
+    agree byte-for-byte.  D is padded to a multiple of 128 lanes: the
+    device copy is the lane-row view of the host array."""
+    from ..kernels.checkout_gather import DEFAULT_BN
     from ..kernels.plan_launch import LANES
     bn = DEFAULT_BN if block_n is None else block_n
-    blk_d = DEFAULT_BD if block_d is None else block_d
     d = max((p.block.shape[1] for p in parts), default=0)
-    # the lane tile is a multiple of 128 lanes, so the padded width is too
-    bd = min(blk_d, -(-d // LANES) * LANES) if d else blk_d
-    d_pad = -(-max(d, 1) // bd) * bd
+    d_pad = -(-max(d, 1) // LANES) * LANES
     seg = np.array([-(-p.block.shape[0] // bn) * bn for p in parts], np.int64)
     row_offsets = np.concatenate([[0], np.cumsum(seg)[:-1]]).astype(np.int64) \
         if len(parts) else np.zeros(0, np.int64)
     bounds = row_offsets + seg
     total = max(int(seg.sum()), bn)
     dtype = parts[0].block.dtype if parts else np.dtype(np.int32)
-    return bn, row_offsets, bounds, d, bd, d_pad, total, dtype
+    return bn, row_offsets, bounds, d, d_pad, total, dtype
 
 
 def _select_parts(store, pids):
@@ -683,14 +687,13 @@ def _select_parts(store, pids):
 
 
 def estimate_superblock_bytes(store, *, block_n: Optional[int] = None,
-                              block_d: Optional[int] = None,
                               pids: Optional[Sequence[int]] = None) -> int:
     """Host bytes a ``build_superblock`` call would allocate (the device
     copy pins the same amount), WITHOUT building it — the memory-budget
     check reads this before committing to the copy.  ``pids`` restricts the
     estimate to a partition group."""
-    _, _, _, _, _, d_pad, total, dtype = _superblock_layout(
-        _select_parts(store, pids), block_n, block_d)
+    _, _, _, _, d_pad, total, dtype = _superblock_layout(
+        _select_parts(store, pids), block_n)
     return total * d_pad * np.dtype(dtype).itemsize
 
 
@@ -711,37 +714,35 @@ def _cached_superblock_need(store) -> int:
     return need
 
 
-def partition_segment_bytes(store, *, block_n: Optional[int] = None,
-                            block_d: Optional[int] = None) -> np.ndarray:
+def partition_segment_bytes(store, *,
+                            block_n: Optional[int] = None) -> np.ndarray:
     """Per-partition BN-aligned segment bytes under the superblock layout —
     the additive unit the group former packs against the budget (a group's
     superblock is the concatenation of its members' segments)."""
-    _, row_offsets, bounds, _, _, d_pad, _, dtype = _superblock_layout(
-        store.partitions, block_n, block_d)
+    _, row_offsets, bounds, _, d_pad, _, dtype = _superblock_layout(
+        store.partitions, block_n)
     return (bounds - row_offsets) * d_pad * np.dtype(dtype).itemsize
 
 
 def build_superblock(store, *, block_n: Optional[int] = None,
-                     block_d: Optional[int] = None,
                      pids: Optional[Sequence[int]] = None) -> Superblock:
     """Concatenate ``store.partitions`` blocks (padded to a common D) into
     one Superblock — all of them, or the partition group ``pids``."""
     parts = _select_parts(store, pids)
-    bn, row_offsets, bounds, d, bd, d_pad, total, dtype = _superblock_layout(
-        parts, block_n, block_d)
+    bn, row_offsets, bounds, d, d_pad, total, dtype = _superblock_layout(
+        parts, block_n)
     host = np.zeros((total, d_pad), dtype=dtype)
     for p, off in zip(parts, row_offsets):
         r, pd = p.block.shape
         host[off:off + r, :pd] = p.block
     return Superblock(host=host, row_offsets=row_offsets, bounds=bounds,
-                      d=d, bd=bd, block_n=bn,
+                      d=d, block_n=bn,
                       epoch=int(getattr(store, "epoch", 0)),
                       pids=None if pids is None
                       else np.asarray(list(pids), np.int64))
 
 
 def get_superblock(store, *, block_n: Optional[int] = None,
-                   block_d: Optional[int] = None,
                    max_bytes: Optional[int] = None
                    ) -> tuple[Optional[Superblock], bool]:
     """Epoch-keyed superblock cache, attached to the store.
@@ -765,19 +766,18 @@ def get_superblock(store, *, block_n: Optional[int] = None,
             store._superblock_cache = cache
         except AttributeError:          # store forbids attributes: no cache
             cache = None
-    key = (block_n, block_d)
+    key = (block_n,)
     epoch = int(getattr(store, "epoch", 0))
     if cache is not None:
         sb = cache.get(key)
         if sb is not None and sb.epoch == epoch:
             return sb, True
     if max_bytes is not None:
-        need = estimate_superblock_bytes(store, block_n=block_n,
-                                         block_d=block_d)
+        need = estimate_superblock_bytes(store, block_n=block_n)
         if need > max_bytes:
             _log_budget_refusal(store, need, max_bytes, epoch)
             return None, False
-    sb = build_superblock(store, block_n=block_n, block_d=block_d)
+    sb = build_superblock(store, block_n=block_n)
     sb.cache_key = key
     if cache is not None:
         cache[key] = sb
@@ -889,7 +889,7 @@ def reinstall_superblock(store, sb: Optional[Superblock]) -> bool:
         except AttributeError:
             sb._device = None
             return False
-    cache[sb.cache_key if sb.cache_key is not None else (None, None)] = sb
+    cache[sb.cache_key if sb.cache_key is not None else (None,)] = sb
     return True
 
 
@@ -947,12 +947,10 @@ class SuperblockGroups:
     migration, in which case ``migrate_groups`` releases it)."""
 
     def __init__(self, store, budget: int, *,
-                 block_n: Optional[int] = None,
-                 block_d: Optional[int] = None):
+                 block_n: Optional[int] = None):
         self.store = store
         self.budget = int(budget)
         self.block_n = block_n
-        self.block_d = block_d
         self.epoch = int(getattr(store, "epoch", 0))
         # pinned group superblocks, LRU order (oldest first)
         self.groups: "collections.OrderedDict[tuple, Superblock]" = \
@@ -970,8 +968,10 @@ class SuperblockGroups:
         self.groups_touched = 0
         self.straggler_requests = 0
         self.auto_regroups = 0      # heat-drift regroups maybe_regroup fired
-        self.migrated = 0           # groups migrated in place (segment_move)
-        self.extended = 0           # groups grown in place by ingest waves
+        self.migrated = 0           # groups migrated on the device
+                                    # (segment_move)
+        self.extended = 0           # groups grown in place on the device by
+                                    # ingest waves (segment_append)
         self.lazy_rebuilds = 0      # group migrations that fell back to a
                                     # rebuild on next touch
         self.append_evictions = 0   # ingest extensions that fell back to
@@ -1003,8 +1003,7 @@ class SuperblockGroups:
         (permanently perpart-routed)."""
         store = self.store
         self.epoch = int(getattr(store, "epoch", 0))
-        seg = partition_segment_bytes(store, block_n=self.block_n,
-                                      block_d=self.block_d)
+        seg = partition_segment_bytes(store, block_n=self.block_n)
         n = len(seg)
         self.pid_to_group.clear()
         self.straggler_pids.clear()
@@ -1028,8 +1027,7 @@ class SuperblockGroups:
             if cur:
                 key = tuple(sorted(cur))
                 self.group_bytes[key] = estimate_superblock_bytes(
-                    self.store, block_n=self.block_n, block_d=self.block_d,
-                    pids=key)
+                    self.store, block_n=self.block_n, pids=key)
                 self.planned.append(key)
                 for q in cur:
                     self.pid_to_group[q] = key
@@ -1190,13 +1188,11 @@ class SuperblockGroups:
         need = self.group_bytes.get(key)
         if need is None:
             need = estimate_superblock_bytes(
-                self.store, block_n=self.block_n, block_d=self.block_d,
-                pids=key)
+                self.store, block_n=self.block_n, pids=key)
             self.group_bytes[key] = need
         if not self._make_room(need, protected):
             return None
-        sb = build_superblock(self.store, block_n=self.block_n,
-                              block_d=self.block_d, pids=key)
+        sb = build_superblock(self.store, block_n=self.block_n, pids=key)
         sb.cache_key = key
         self.groups[key] = sb
         self.pinned_bytes += int(sb.host.nbytes)
@@ -1280,7 +1276,7 @@ def migrate_groups(store, plan, taken: Sequence[Superblock], *,
     partitions that morphed out of them; the group superblock migrates
     incrementally (``migrate_superblock(pids=...)`` — device tiles reused,
     delta-only upload) and re-pins under the budget.  Groups that dissolved
-    (no new partition morphed from them), changed tiling, or no longer fit
+    (no new partition morphed from them), changed width, or no longer fit
     are evicted (device released).  Returns the migrated-group count."""
     mgr = getattr(store, "_superblock_groups", None)
     if mgr is None:
@@ -1309,16 +1305,16 @@ def migrate_groups(store, plan, taken: Sequence[Superblock], *,
             # group pinned during this call is protected, so the fit test is
             # exactly "does it fit in the remaining budget"
             est = estimate_superblock_bytes(store, block_n=mgr.block_n,
-                                            block_d=mgr.block_d, pids=new_pids)
+                                            pids=new_pids)
             if mgr.pinned_bytes + est > mgr.budget:
                 old_sb._device = None
                 continue
             try:
-                new_sb, _ = migrate_superblock(store, old_sb, plan,
-                                               pids=new_pids,
-                                               use_kernel=use_kernel,
-                                               install=False)
-            except ValueError:      # tiling changed: rebuild on next touch
+                new_sb, st = migrate_superblock(store, old_sb, plan,
+                                                pids=new_pids,
+                                                use_kernel=use_kernel,
+                                                install=False)
+            except ValueError:      # width changed: rebuild on next touch
                 old_sb._device = None
                 mgr.lazy_rebuilds += 1
                 continue
@@ -1332,7 +1328,7 @@ def migrate_groups(store, plan, taken: Sequence[Superblock], *,
             if mgr.install(new_sb, protected=kept):
                 kept.add(tuple(int(q) for q in np.asarray(new_sb.pids)))
                 migrated += 1
-                mgr.migrated += 1
+                mgr.migrated += int(st.used_device)
         try:
             mgr.plan_groups()       # regroup leftovers around the survivors
         except Exception:
@@ -1810,8 +1806,8 @@ def migrate_superblock(store, old_sb: Superblock, plan, *,
 
       * every BN-row tile of the new superblock whose rows sit consecutively
         inside one aligned segment of the OLD superblock is copied
-        device-to-device by the ``kernels.ops.segment_move`` pallas_call
-        (ONE launch for the whole migration) — these tiles never cross the
+        device-to-device by ``kernels.ops.segment_move`` (one launch per
+        SMEM-sized slice of the tile plan) — these tiles never cross the
         host link again;
       * only the remaining tiles (rows migration moved across partition
         boundaries, plus genuinely new rows) are packed into a small delta
@@ -1840,13 +1836,12 @@ def migrate_superblock(store, old_sb: Superblock, plan, *,
     parts = _select_parts(store, pids)
     plan_idx = list(range(len(parts))) if pids is None \
         else [int(q) for q in pids]
-    bn, row_offsets, bounds, d, bd, d_pad, total, dtype = _superblock_layout(
-        parts, old_sb.block_n, old_sb.bd)
-    if d != old_sb.d or bd != old_sb.bd or bn != old_sb.block_n:
+    bn, row_offsets, bounds, d, d_pad, total, dtype = _superblock_layout(
+        parts, old_sb.block_n)
+    if d != old_sb.d:
         raise ValueError(
-            f"migration changed the superblock tiling (d {old_sb.d}->{d}, "
-            f"bd {old_sb.bd}->{bd}, bn {old_sb.block_n}->{bn}) — rebuild "
-            "with build_superblock instead")
+            f"migration changed the superblock width (d {old_sb.d}->{d}) — "
+            "rebuild with build_superblock instead")
     n_tiles = total // bn
     sel = np.ones(n_tiles, np.int32)          # default: delta
     starts = np.zeros(n_tiles, np.int32)
@@ -1926,7 +1921,7 @@ def migrate_superblock(store, old_sb: Superblock, plan, *,
     bytes_uploaded = 0
 
     new_sb = Superblock(host=host, row_offsets=row_offsets, bounds=bounds,
-                        d=d, bd=bd, block_n=bn,
+                        d=d, block_n=bn,
                         epoch=int(getattr(store, "epoch", 0)),
                         pids=None if pids is None
                         else np.asarray(plan_idx, np.int64))
@@ -1945,9 +1940,10 @@ def migrate_superblock(store, old_sb: Superblock, plan, *,
                                         sel, starts, block_n=bn,
                                         row_lanes=new_sb.row_lanes)
         new_sb.uploads = 1 if bytes_uploaded else 0
+        new_sb.bytes_uploaded = bytes_uploaded
 
     if install and pids is None:
-        key = getattr(old_sb, "cache_key", None) or (None, None)
+        key = getattr(old_sb, "cache_key", None) or (None,)
         new_sb.cache_key = key
         cache = getattr(store, "_superblock_cache", None)
         if cache is None:
@@ -1994,7 +1990,7 @@ def extend_superblock_after_commit(store, old_sb: Superblock,
     ``pids`` selects a partition GROUP (the new superblock covers those
     slots); None extends a whole-store superblock — a commit that opened a
     brand-new partition appends it as an all-delta segment.  Raises
-    ValueError when the commit changed the tiling (d/bd/bn) — callers
+    ValueError when the commit changed the width (d) — callers
     degrade to eviction + lazy rebuild.  Returns (new_sb, stats);
     ``stats.bytes_uploaded`` is the delta bytes the acceptance gate bounds.
     """
@@ -2006,13 +2002,12 @@ def extend_superblock_after_commit(store, old_sb: Superblock,
     parts_idx = (list(range(len(store.partitions))) if pids is None
                  else [int(q) for q in pids])
     parts = [store.partitions[q] for q in parts_idx]
-    bn, row_offsets, bounds, d, bd, d_pad, total, dtype = _superblock_layout(
-        parts, old_sb.block_n, old_sb.bd)
-    if d != old_sb.d or bd != old_sb.bd or bn != old_sb.block_n:
+    bn, row_offsets, bounds, d, d_pad, total, dtype = _superblock_layout(
+        parts, old_sb.block_n)
+    if d != old_sb.d:
         raise ValueError(
-            f"commit changed the superblock tiling (d {old_sb.d}->{d}, "
-            f"bd {old_sb.bd}->{bd}, bn {old_sb.block_n}->{bn}) — rebuild "
-            "with build_superblock instead")
+            f"commit changed the superblock width (d {old_sb.d}->{d}) — "
+            "rebuild with build_superblock instead")
     n_tiles = total // bn
     sel = np.ones(n_tiles, np.int32)          # default: delta
     starts = np.zeros(n_tiles, np.int32)
@@ -2083,7 +2078,7 @@ def extend_superblock_after_commit(store, old_sb: Superblock,
     n_delta = int((sel == 1).sum())
     bytes_uploaded = 0
     new_sb = Superblock(host=host, row_offsets=row_offsets, bounds=bounds,
-                        d=d, bd=bd, block_n=bn,
+                        d=d, block_n=bn,
                         epoch=int(getattr(store, "epoch", 0)),
                         pids=None if pids is None
                         else np.asarray(parts_idx, np.int64))
@@ -2102,6 +2097,7 @@ def extend_superblock_after_commit(store, old_sb: Superblock,
                                           sel, starts, block_n=bn,
                                           row_lanes=new_sb.row_lanes)
         new_sb.uploads = 1 if bytes_uploaded else 0
+        new_sb.bytes_uploaded = bytes_uploaded
     stats = MigrationStats(
         n_tiles=n_tiles, reused_tiles=reused, delta_tiles=n_delta,
         bytes_uploaded=int(bytes_uploaded), bytes_total=int(host.nbytes),
@@ -2122,12 +2118,14 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
       * a pinned group whose partitions the commit did NOT touch is
         revalidated at the new epoch in place — zero work, zero upload
         (commits only grow the receiving partitions; untouched slots keep
-        their exact blocks), so cold groups STAY pinned;
+        their exact blocks).  Cold groups stay pinned unless a touched
+        group's growth needs their bytes: then they LRU-evict to fit it;
       * a touched superblock (group or whole-store) is extended in place
         via ``extend_superblock_after_commit`` — only the new BN-aligned
-        tiles cross the host link; on any failure (tiling change, budget,
-        injected ``ingest.append`` fault) THAT superblock alone degrades
-        to eviction + lazy rebuild;
+        tiles cross the host link.  Touched groups are protected from
+        each other's growth; on any failure (width change, a budget the
+        cold groups cannot make room in, injected ``ingest.append``
+        fault) THAT superblock alone degrades to eviction + lazy rebuild;
       * genuinely stale entries (pre-dating the commit's epoch) are
         evicted as before.
 
@@ -2204,7 +2202,7 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
             continue
         try:
             need = estimate_superblock_bytes(
-                store, block_n=mgr.block_n, block_d=mgr.block_d, pids=key)
+                store, block_n=mgr.block_n, pids=key)
             grow = need - int(sb.host.nbytes)
             if grow > 0 and not mgr._make_room(grow, protected=kept):
                 raise ValueError(
@@ -2229,7 +2227,7 @@ def refresh_superblocks_after_commit(store, touched_old_grids: dict, *,
         mgr.group_bytes[key] = int(new_sb.host.nbytes)
         mgr.pinned_bytes += int(new_sb.host.nbytes) - int(sb.host.nbytes)
         sb._device = None
-        mgr.extended += 1
+        mgr.extended += int(st.used_device)
         report["extended"] += 1
         report["bytes_uploaded"] += st.bytes_uploaded
         report["delta_tiles"] += st.delta_tiles

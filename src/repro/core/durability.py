@@ -152,7 +152,6 @@ def _groups_meta(store) -> Optional[dict]:
         return None
     return {"budget": int(mgr.budget),
             "block_n": None if mgr.block_n is None else int(mgr.block_n),
-            "block_d": None if mgr.block_d is None else int(mgr.block_d),
             "planned": [[int(q) for q in key] for key in mgr.planned],
             "stragglers": sorted(int(q) for q in mgr.straggler_pids),
             # a kill evicts every pinned group: folding the pinned count
@@ -382,8 +381,7 @@ class StoreDurability:
         if g is not None:
             mgr = SuperblockGroups(
                 store, int(g["budget"]),
-                block_n=None if g["block_n"] is None else int(g["block_n"]),
-                block_d=None if g["block_d"] is None else int(g["block_d"]))
+                block_n=None if g["block_n"] is None else int(g["block_n"]))
             mgr.planned = [tuple(int(q) for q in key)
                            for key in g["planned"]]
             for key in mgr.planned:

@@ -297,7 +297,6 @@ def journal_regroup(mgr) -> None:
     j.append_advisory("regroup", {
         "budget": int(mgr.budget),
         "block_n": None if mgr.block_n is None else int(mgr.block_n),
-        "block_d": None if mgr.block_d is None else int(mgr.block_d),
         "planned": [[int(q) for q in key] for key in mgr.planned],
         "stragglers": sorted(int(q) for q in mgr.straggler_pids)})
 

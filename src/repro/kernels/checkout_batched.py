@@ -210,11 +210,10 @@ def _make_wave_kernel(block_n: int, row_lanes: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "row_lanes",
-                                             "interpret", "max_tiles"))
+                                             "interpret"))
 def checkout_wave(data: jax.Array, starts: jax.Array, mode: jax.Array,
                   hi: jax.Array, *, block_n: int = DEFAULT_BN,
-                  row_lanes: int = 1, interpret: bool = False,
-                  max_tiles: int | None = None) -> jax.Array:
+                  row_lanes: int = 1, interpret: bool = False) -> jax.Array:
     """Execute a cross-partition ``plan_wave`` plan over a lane-row
     superblock, in as few launches as SMEM allows (see ``plan_launch``).
 
@@ -223,7 +222,6 @@ def checkout_wave(data: jax.Array, starts: jax.Array, mode: jax.Array,
     starts: (T*block_n,) int32 superblock rids (rebased by partition offset).
     mode:   (T,) int32 per-tile gather mode (1 = run candidate).
     hi:     (T,) int32 per-tile exclusive row bound for run DMAs.
-    max_tiles: optional cap on tiles per launch (below the SMEM bound).
     Returns (T*block_n*row_lanes, 128) packed lane-rows: logical row i is
     lane-rows [i*row_lanes, (i+1)*row_lanes); slice per version with the
     plan's segments after the host reshape to (T*block_n, row_lanes*128).
@@ -235,4 +233,4 @@ def checkout_wave(data: jax.Array, starts: jax.Array, mode: jax.Array,
          hi.astype(jnp.int32)], [data],
         n_tiles=t, block_rows=block_n * row_lanes, dtype=data.dtype,
         scratch_shapes=[pltpu.SemaphoreType.DMA((block_n,))],
-        interpret=interpret, max_tiles=max_tiles)
+        interpret=interpret)

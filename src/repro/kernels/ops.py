@@ -232,27 +232,35 @@ def segment_append(src, delta, sel, starts, *,
         interpret=not _on_tpu() if interpret is None else interpret)
 
 
+def _record_block(block_r: int, r: int) -> int:
+    """Records per grid step of the bitmap kernels: ``block_r``, clamped to
+    the lane-padded table for tables smaller than one block."""
+    if block_r <= 0 or block_r % LANES:
+        raise ValueError(f"block_r={block_r} is not a positive multiple of "
+                         f"{LANES} (records run along the lane axis)")
+    return min(block_r, -(-max(r, 1) // LANES) * LANES)
+
+
 def membership_scan(bitmap, vid: int, *, block_r: int = _vm.DEFAULT_BR):
-    """(mask, per-block counts) for version ``vid`` over the bitset vlists."""
+    """(mask, per-``block_r`` counts) for version ``vid`` over the (W, R)
+    bitset vlists of ``build_bitmap``."""
     bitmap = jnp.asarray(bitmap)
-    r = bitmap.shape[0]
-    br = -(-min(block_r, max(r, 1)) // LANES) * LANES
-    padded = _pad_axis(bitmap, br, axis=0)
-    mask, cnt = _vm.membership_scan(padded.T, vid=vid, block_r=br,
-                                    interpret=not _on_tpu())
+    r = bitmap.shape[1]
+    br = _record_block(block_r, r)
+    mask, cnt = _vm.membership_scan(_pad_axis(bitmap, br, axis=1), vid=vid,
+                                    block_r=br, interpret=not _on_tpu())
     return mask[:r], cnt
 
 
 def version_aggregate(bitmap, values, *, block_r: int = _va.DEFAULT_BR):
-    """Per-version sums of ``values`` over the bitset vlists; (n_versions,)
-    prefix of the (W*32,) kernel output is the meaningful part."""
+    """Per-version sums of ``values`` over the (W, R) bitset vlists of
+    ``build_bitmap``; the (n_versions,) prefix of the (W*32,) kernel
+    output is the meaningful part."""
     bitmap = jnp.asarray(bitmap)
     values = jnp.asarray(values)
-    r = bitmap.shape[0]
-    br = -(-min(block_r, max(r, 1)) // LANES) * LANES
-    padded_bm = _pad_axis(bitmap, br, axis=0)
-    padded_v = _pad_axis(values, br, axis=0)
-    return _va.version_aggregate(padded_bm.T, padded_v, block_r=br,
+    br = _record_block(block_r, bitmap.shape[1])
+    return _va.version_aggregate(_pad_axis(bitmap, br, axis=1),
+                                 _pad_axis(values, br, axis=0), block_r=br,
                                  interpret=not _on_tpu())
 
 

@@ -32,18 +32,15 @@ PLAN_SMEM_BYTES = 512 * 1024  # prefetched plan bytes per launch: half of a
                               # the compiler's own scalars and semaphores
 
 
-def launch_ranges(n_tiles: int, bytes_per_tile: int,
-                  max_tiles: int | None = None) -> list[tuple[int, int]]:
+def launch_ranges(n_tiles: int, bytes_per_tile: int) -> list[tuple[int, int]]:
     """(first tile, tile count) of each launch covering ``n_tiles``."""
     per = max(1, PLAN_SMEM_BYTES // max(1, bytes_per_tile))
-    if max_tiles is not None:
-        per = min(per, max(1, int(max_tiles)))
     return [(t0, min(per, n_tiles - t0)) for t0 in range(0, n_tiles, per)]
 
 
 def planned_tile_call(kernel, scalars, operands, *, n_tiles: int,
                       block_rows: int, dtype, scratch_shapes,
-                      interpret: bool, max_tiles: int | None = None):
+                      interpret: bool):
     """Run ``kernel`` over tiles ``0..n_tiles-1`` in SMEM-sized launches.
 
     kernel:   the Pallas body, called as ``kernel(*scalar_refs,
@@ -59,7 +56,7 @@ def planned_tile_call(kernel, scalars, operands, *, n_tiles: int,
     out_shape = jax.ShapeDtypeStruct((n_tiles * block_rows, LANES), dtype)
     n_fixed = len(scalars) + len(operands)
     out = None
-    for t0, n in launch_ranges(n_tiles, 4 * sum(per_tile), max_tiles):
+    for t0, n in launch_ranges(n_tiles, 4 * sum(per_tile)):
         chunk = [s[t0 * k:(t0 + n) * k] for s, k in zip(scalars, per_tile)]
         args = [*chunk, *operands] + ([] if out is None else [out])
         spec = pltpu.PrefetchScalarGridSpec(

@@ -28,18 +28,20 @@ def gather_row_tiles_ref(data: jax.Array, tile_idx: jax.Array, block_n: int) -> 
 
 def membership_scan_ref(bitmap: jax.Array, vid: int, block_r: int
                         ) -> tuple[jax.Array, jax.Array]:
+    """bitmap: (W, R) uint32, as ``build_bitmap`` lays it out."""
     word, bit = vid // 32, vid % 32
-    mask = ((bitmap[:, word] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.int32)
+    mask = ((bitmap[word] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.int32)
     cnt = mask.reshape(-1, block_r).sum(axis=1).astype(jnp.int32)
     return mask, cnt
 
 
 def version_aggregate_ref(bitmap: jax.Array, values: jax.Array) -> jax.Array:
-    r, w = bitmap.shape
+    """bitmap: (W, R) uint32, as ``build_bitmap`` lays it out."""
+    w, r = bitmap.shape
     shifts = jnp.arange(32, dtype=jnp.uint32)
-    bits = ((bitmap[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1))  # (R, W, 32)
+    bits = ((bitmap[:, :, None] >> shifts[None, None, :]) & jnp.uint32(1))  # (W, R, 32)
     vals = values.astype(jnp.float32)
-    out = jnp.einsum("rwb,r->wb", bits.astype(jnp.float32), vals)
+    out = jnp.einsum("wrb,r->wb", bits.astype(jnp.float32), vals)
     return out.reshape(w * 32)
 
 

@@ -65,11 +65,10 @@ def _make_kernel(block_n: int, row_lanes: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "row_lanes",
-                                             "interpret", "max_tiles"))
+                                             "interpret"))
 def segment_move(src: jax.Array, delta: jax.Array, sel: jax.Array,
                  starts: jax.Array, *, block_n: int = DEFAULT_BN,
-                 row_lanes: int = 1, interpret: bool = False,
-                 max_tiles: int | None = None) -> jax.Array:
+                 row_lanes: int = 1, interpret: bool = False) -> jax.Array:
     """Assemble a migrated superblock: T output tiles, in as few launches
     as SMEM allows (see ``plan_launch``).
 
@@ -92,4 +91,4 @@ def segment_move(src: jax.Array, delta: jax.Array, sel: jax.Array,
         [sel.astype(jnp.int32), starts.astype(jnp.int32)], [src, delta],
         n_tiles=sel.shape[0], block_rows=block_n * row_lanes,
         dtype=src.dtype, scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
-        interpret=interpret, max_tiles=max_tiles)
+        interpret=interpret)

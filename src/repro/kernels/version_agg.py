@@ -10,8 +10,8 @@ aggregate over a value column is
 i.e. a {0,1}-matrix × vector product.  Unpacking 32 versions from one uint32
 word turns the CSR segment-sum (scatter-heavy, TPU-hostile) into a dense
 (32, BR) × (BR,) reduction per word row — VPU-friendly, no scatters,
-sequential HBM traffic.  The kernel reads the TRANSPOSED bitmap (W, R), so
-records run along the 128-lane axis; the grid walks record blocks and
+sequential HBM traffic.  The bitmap is (W, R) as ``build_bitmap`` lays it
+out, so records run along the 128-lane axis; the grid walks record blocks and
 accumulates into the one (W*32, 1) output block every step revisits.
 """
 from __future__ import annotations
@@ -45,16 +45,16 @@ def _agg_kernel(bm_ref, val_ref, o_ref, *, n_words: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def version_aggregate(bitmap_t: jax.Array, values: jax.Array, *,
+def version_aggregate(bitmap: jax.Array, values: jax.Array, *,
                       block_r: int = DEFAULT_BR, interpret: bool = False
                       ) -> jax.Array:
     """out: (W*32,) float32 — per-version sums of ``values`` (masked upstream
     for predicates; use values=1.0 for COUNT).
 
-    bitmap_t: (W, R) uint32 — the TRANSPOSED bitset; values: (R,) float32;
+    bitmap: (W, R) uint32 bitset (``build_bitmap``); values: (R,) float32;
     R a multiple of block_r, block_r a multiple of 128.
     """
-    w, r = bitmap_t.shape
+    w, r = bitmap.shape
     br = min(block_r, r)
     assert r % br == 0 and br % LANES == 0, (r, br)
     out = pl.pallas_call(
@@ -65,5 +65,5 @@ def version_aggregate(bitmap_t: jax.Array, values: jax.Array, *,
         out_specs=pl.BlockSpec((w * 32, 1), lambda rb: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((w * 32, 1), jnp.float32),
         interpret=interpret,
-    )(bitmap_t, values.astype(jnp.float32).reshape(1, r))
+    )(bitmap, values.astype(jnp.float32).reshape(1, r))
     return out[:, 0]

@@ -1,6 +1,8 @@
 """Batched checkout engine: fused multi-version kernel vs the NumPy oracle,
 single-launch accounting, vectorized host paths byte-identical to the seed
 loop implementations, and serve-layer wave coalescing."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,9 +76,10 @@ def test_checkout_batched_single_pallas_call(rng, monkeypatch):
 
 
 @pytest.mark.parametrize("row_lanes", [1, 2])
-@pytest.mark.parametrize("max_tiles", [1, 3, 7])
-def test_checkout_wave_split_launches_bit_identical(rng, row_lanes,
-                                                   max_tiles):
+@pytest.mark.parametrize("tiles_per_launch", [1, 3, 7])
+def test_checkout_wave_split_launches_bit_identical(rng, capped_launches,
+                                                   row_lanes,
+                                                   tiles_per_launch):
     """A plan too big for one launch's SMEM runs as several launches into
     one aligned output: bit-identical to a single launch and to the oracle,
     for one- and two-lane-row (D=128/256) superblocks."""
@@ -86,13 +89,15 @@ def test_checkout_wave_split_launches_bit_identical(rng, row_lanes,
     plan = _cb.plan_batched(rls)
     args = (jnp.asarray(data.reshape(-1, 128)), jnp.asarray(plan.starts),
             jnp.asarray(plan.mode), jnp.full(plan.n_tiles, r, jnp.int32))
-    one = np.asarray(_cb.checkout_wave(*args, row_lanes=row_lanes,
-                                       interpret=True))
-    split = np.asarray(_cb.checkout_wave(*args, row_lanes=row_lanes,
-                                         interpret=True,
-                                         max_tiles=max_tiles))
-    np.testing.assert_array_equal(split, one)
-    packed = split.reshape(-1, d)
+    run = functools.partial(_cb.checkout_wave, *args, row_lanes=row_lanes,
+                            interpret=True)
+    one = np.asarray(run())
+    # 40 B of plan per tile: BN=8 starts + mode + hi, int32 each
+    split, launches = capped_launches(_cb.checkout_wave, run,
+                                      40 * tiles_per_launch)
+    assert launches == -(-plan.n_tiles // tiles_per_launch) > 1
+    np.testing.assert_array_equal(np.asarray(split), one)
+    packed = np.asarray(split).reshape(-1, d)
     for k, want in enumerate(ref.gather_batched_ref(data, rls)):
         np.testing.assert_array_equal(packed[plan.segment(k, 8)], want)
 

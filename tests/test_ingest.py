@@ -248,8 +248,9 @@ def test_segment_append_kernel_modes():
 
 
 @pytest.mark.parametrize("kernel", ["segment_append", "segment_move"])
-@pytest.mark.parametrize("max_tiles", [1, 4])
-def test_segment_kernels_split_launches_bit_identical(kernel, max_tiles):
+@pytest.mark.parametrize("tiles_per_launch", [1, 4])
+def test_segment_kernels_split_launches_bit_identical(kernel, tiles_per_launch,
+                                                      capped_launches):
     """Plans bigger than one launch's SMEM run as several launches into one
     output — bit-identical to a single launch."""
     import importlib
@@ -264,8 +265,12 @@ def test_segment_kernels_split_launches_bit_identical(kernel, max_tiles):
                       rng.integers(0, 3 * bn, t)).astype(np.int32)
     run = functools.partial(getattr(mod, kernel), src, delta, sel, starts,
                             block_n=bn, interpret=True)
-    np.testing.assert_array_equal(np.asarray(run(max_tiles=max_tiles)),
-                                  np.asarray(run()))
+    one = np.asarray(run())
+    # 8 B of plan per tile: sel + start, int32 each
+    split, launches = capped_launches(getattr(mod, kernel), run,
+                                      8 * tiles_per_launch)
+    assert launches == -(-t // tiles_per_launch) > 1
+    np.testing.assert_array_equal(np.asarray(split), one)
 
 
 def test_segment_append_rejects_ragged_width():
@@ -359,6 +364,40 @@ def test_cold_pinned_groups_stay_pinned():
     # served rows off the refreshed groups match the plain gather
     for v in (0, store.graph.n_versions - 1):
         got = checkout_partitioned(store, [v], use_kernel=False)[0]
+        np.testing.assert_array_equal(np.asarray(got),
+                                      store.data[store.graph.rlist(v)])
+
+
+def test_full_budget_growth_evicts_a_cold_group():
+    """With the budget full, a touched group that grows makes room by
+    LRU-evicting a COLD group and is extended in place on the device — it
+    is never evicted itself (an append eviction)."""
+    store = _mkstore(n_versions=12, n_records=512, parts=6)
+    mgr = get_superblock_groups(
+        store, budget=estimate_superblock_bytes(store), create=True)
+    mgr.warm(device=True)
+    assert mgr.pinned_bytes == mgr.budget and len(mgr.groups) >= 2
+    before = dict(mgr.groups)
+    slot = int(store.vid_to_pid[0])
+    (hot,) = [k for k in before if slot in k]
+    n0 = store.graph.n_records
+    new = np.random.default_rng(5).integers(0, 1 << 20, (24, 8)) \
+        .astype(np.int32)
+    store.commit_many([{"rlist": np.concatenate(
+        [store.graph.rlist(0), np.arange(n0, n0 + 24)]),
+        "parent": 0, "new_rows": new}])
+    assert mgr.append_evictions == 0 and mgr.extended == 1
+    grown = mgr.groups[hot]
+    assert grown is not before[hot] and grown.epoch == store.epoch
+    assert 0 < grown.bytes_uploaded < grown.host.nbytes    # delta only
+    evicted = set(before) - set(mgr.groups)
+    assert evicted and hot not in evicted
+    assert mgr.pinned_bytes <= mgr.budget
+    assert mgr.pins - mgr.evictions == len(mgr.groups)
+    np.testing.assert_array_equal(
+        grown.host, build_superblock(store, pids=hot).host)
+    for v in (0, store.graph.n_versions - 1):
+        got = checkout_partitioned(store, [v], use_kernel=True)[0]
         np.testing.assert_array_equal(np.asarray(got),
                                       store.data[store.graph.rlist(v)])
 

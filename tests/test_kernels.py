@@ -50,7 +50,7 @@ def test_tiled_waste_drops_for_dense_runs(rng):
 
 
 @pytest.mark.parametrize("r,n_versions,block_r", [
-    (256, 33, 64),
+    (256, 33, 128),
     (1000, 70, 256),
     (513, 100, 128),
 ])
@@ -60,17 +60,19 @@ def test_membership_scan_sweep(r, n_versions, block_r, rng):
     bm = ops.build_bitmap(rlists, r)
     for vid in (0, n_versions // 2, n_versions - 1):
         mask, cnt = ops.membership_scan(bm, vid=vid, block_r=block_r)
-        m_ref, _ = ref.membership_scan_ref(
-            jnp.asarray(np.pad(bm, ((0, (-r) % min(block_r, r)), (0, 0)))),
-            vid, min(block_r, r))
+        m_ref, c_ref = ref.membership_scan_ref(
+            jnp.asarray(np.pad(bm, ((0, 0), (0, (-r) % block_r)))),
+            vid, block_r)
         expect = np.zeros(r, np.int32)
         expect[rlists[vid]] = 1
         np.testing.assert_array_equal(np.asarray(mask), expect)
+        np.testing.assert_array_equal(np.asarray(mask), np.asarray(m_ref)[:r])
+        np.testing.assert_array_equal(np.asarray(cnt), np.asarray(c_ref))
         assert int(np.asarray(cnt).sum()) == len(rlists[vid])
 
 
 @pytest.mark.parametrize("r,n_versions,block_r", [
-    (256, 16, 64),
+    (256, 16, 128),
     (1024, 64, 256),
     (777, 40, 128),
 ])
@@ -86,6 +88,17 @@ def test_version_aggregate_sweep(r, n_versions, block_r, rng):
     oracle = np.asarray(ref.version_aggregate_ref(jnp.asarray(bm),
                                                   jnp.asarray(vals)))
     np.testing.assert_allclose(agg[:len(oracle)], oracle, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("kernel", ["membership_scan", "version_aggregate"])
+def test_bitmap_kernels_reject_unaligned_block_r(kernel):
+    """Records run along the 128-lane axis: a block that is not a whole
+    number of lane tiles is refused, not silently rounded."""
+    bm = ops.build_bitmap([np.arange(10), np.arange(5, 50)], 256)
+    args = {"membership_scan": dict(vid=1),
+            "version_aggregate": dict(values=np.ones(256, np.float32))}
+    with pytest.raises(ValueError, match="multiple of 128"):
+        getattr(ops, kernel)(bm, block_r=64, **args[kernel])
 
 
 def test_version_aggregate_count_mode(rng):
