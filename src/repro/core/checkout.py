@@ -116,8 +116,9 @@ whose ΣR×D superblock would exceed it refuses to pin the whole-store copy
       │    ``max_bytes`` budget with LRU eviction of cold groups
       └─ _grouped_wave                             [wave routing/splitting]
       │    the wave's vids split by group; each TOUCHED PINNED group runs
-      │    as ONE fused ``checkout_wave`` pallas_call over that group's
-      │    superblock (launches == touched pinned groups); only genuinely
+      │    as ONE fused ``checkout_wave`` gather over that group's
+      │    superblock (launches == touched pinned groups; a gather is one
+      │    pallas_call per SMEM-sized plan slice); only genuinely
       │    unpinned stragglers (partitions bigger than the whole budget, or
       │    groups the LRU could not co-pin this wave) route through the
       │    per-partition engine
@@ -1695,7 +1696,7 @@ def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
     """Route one wave through the partition-group layer.
 
     The wave's vids split by group; every touched group that is (or can
-    be) pinned runs as ONE fused ``checkout_wave`` pallas_call over its
+    be) pinned runs as ONE fused ``checkout_wave`` gather over its
     group superblock — kernel launches == touched pinned groups, and every
     launched gather stays device-resident inside the returned
     ``WaveResult`` (the per-group device→host transfers all defer to
@@ -2243,7 +2244,8 @@ def checkout_partitioned(store, vids: Sequence[int], *,
     """Batched checkout over a PartitionedCVD, results in request order.
 
     engine="wave" (default): ONE fused gather for the whole wave via the
-    device-resident superblock — a single pallas_call regardless of how many
+    device-resident superblock — one gather (``kernels.plan_launch``: a
+    pallas_call per SMEM-sized plan slice) regardless of how many
     partitions the vids span.  engine="perpart": the previous one fused
     gather PER PARTITION (kept as oracle and benchmark baseline).
 

@@ -136,8 +136,8 @@ def test_apply_migration_bumps_epoch_and_rejects_wrong_plan(rng):
 
 # ------------------------------------------------------ migrate_superblock --
 def test_migrate_superblock_bit_identical_and_reuses_device(rng):
-    """Kernel path: the migrated superblock (assembled by ONE segment_move
-    pallas_call off the OLD device buffer + a delta upload) is bit-identical
+    """Kernel path: the migrated superblock (assembled by segment_move off
+    the OLD device buffer + a delta upload) is bit-identical
     to a from-scratch rebuild on every valid row, and uploads strictly fewer
     bytes."""
     store, w = _store(rng, n_partitions=3, seed=13)
