@@ -264,10 +264,11 @@ import functools
 import logging
 import os
 import time
-from typing import Optional, Sequence
+from typing import ClassVar, Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from .faults import fault_point
 from .graph import BipartiteGraph
 
@@ -297,19 +298,22 @@ def _fused_host_gather(data: np.ndarray, rlists: Sequence[np.ndarray]
 
 
 def checkout_rlists(data: np.ndarray, rlists: Sequence[np.ndarray], *,
-                    use_kernel: Optional[bool] = None) -> list[np.ndarray]:
+                    use_kernel: Optional[bool] = None,
+                    stages: Optional["WaveStages"] = None
+                    ) -> list[np.ndarray]:
     """Materialize K rlists from one data block in a single fused pass.
 
     use_kernel: True -> Pallas ``checkout_batched`` (ONE kernel launch;
     interpret mode off-TPU), False -> fused host gather, None -> kernel on
-    TPU, host otherwise (probe cached per process).
+    TPU, host otherwise (probe cached per process).  The kernel adds the
+    bytes it uploads to ``stages.h2d_bytes``.
     """
     if use_kernel is None:
         use_kernel = _default_use_kernel()
     if not use_kernel:
         return _fused_host_gather(np.asarray(data), rlists)
     from ..kernels import ops as K
-    outs, _ = K.checkout_batched(data, rlists)
+    outs, _ = K.checkout_batched(data, rlists, stages=stages)
     return outs
 
 
@@ -495,6 +499,28 @@ def _wave_launcher() -> concurrent.futures.ThreadPoolExecutor:
 
 
 @dataclasses.dataclass
+class WaveStages:
+    """Host seconds and bytes of one read wave, by stage.  The stages never
+    nest, so each second lands in exactly one field.  Dispatch fills the
+    ``DISPATCH`` fields, the delivery join (``WaveResult.materialize``) the
+    ``DELIVERY`` ones; the serve layer sums each half into
+    ``serve.checkout.CheckoutStats`` when that half of the wave is done."""
+    DISPATCH: ClassVar[tuple] = ("plan_s", "launch_s", "pin_s",
+                                 "straggler_s", "h2d_bytes")
+    DELIVERY: ClassVar[tuple] = ("device_wait_s", "d2h_s", "d2h_bytes")
+    plan_s: float = 0.0         # plan_wave_cached, per gather
+    launch_s: float = 0.0       # the jitted gather calls: trace, lower and
+                                # compile of a new shape, then the enqueue
+    pin_s: float = 0.0          # superblock pins: host build, evictions,
+                                # first upload
+    straggler_s: float = 0.0    # the per-partition straggler batch
+    h2d_bytes: int = 0          # superblock uploads + straggler partitions
+    device_wait_s: float = 0.0  # blocking until each packed gather is done
+    d2h_s: float = 0.0          # device→host copies and per-vid splits
+    d2h_bytes: int = 0          # packed gathers copied to the host
+
+
+@dataclasses.dataclass
 class _WavePart:
     """One contiguous gather of a wave: either a still-device-resident
     packed block plus its per-vid split plan, or pre-materialized host
@@ -509,20 +535,34 @@ class _WavePart:
     d: int = 0                          # valid feature width of ``packed``
     width: int = 0                      # padded row width: ``packed`` holds
                                         # lane-rows, width/128 per row
+    stages: Optional[WaveStages] = None  # the wave's counters (device parts)
 
     def split(self) -> list:
         """Force this part to host blocks: join the in-flight launch, ONE
         device→host transfer of the packed gather, then per-vid zero-copy
-        views."""
+        views.  The wait for the kernel and the copy are timed apart (the
+        copy waits on the kernel anyway, so the split adds no sync)."""
         if self.mats is None:
             # fires BEFORE the transfer consumes anything: the device handle
             # survives an injected failure, so a delivery retry succeeds
             fault_point("serve.transfer")
-            packed = self.packed
-            if isinstance(packed, concurrent.futures.Future):
-                packed = packed.result()
-            arr = np.asarray(packed).reshape(-1, self.width)[:, :self.d]
-            self.mats = [arr[seg] for seg in self.segments]
+            t0 = time.perf_counter()
+            with obs.span("checkout.device_wait"):
+                packed = self.packed
+                if isinstance(packed, concurrent.futures.Future):
+                    packed = packed.result()
+                wait = getattr(packed, "block_until_ready", None)
+                if wait is not None:
+                    wait()
+            t1 = time.perf_counter()
+            nbytes = int(packed.nbytes)
+            with obs.span("checkout.d2h", bytes=nbytes):
+                arr = np.asarray(packed).reshape(-1, self.width)[:, :self.d]
+                self.mats = [arr[seg] for seg in self.segments]
+            if self.stages is not None:
+                self.stages.device_wait_s += t1 - t0
+                self.stages.d2h_s += time.perf_counter() - t1
+                self.stages.d2h_bytes += nbytes
             self.packed = None          # release the device handle
             self.segments = None
         return self.mats
@@ -540,9 +580,11 @@ class WaveResult:
     the same handle (``ready()`` is immediately True), so callers drive
     every tier identically.  ``materialize()`` is idempotent and caches its
     result; it is bit-identical to the eager (``device_out=False``) path,
-    which is literally this handle materialized at once."""
+    which is literally this handle materialized at once.  ``stages`` holds
+    the wave's host seconds and bytes by stage (``WaveStages``)."""
     n: int                              # wave length (vids requested)
     parts: list                         # _WavePart covering positions 0..n-1
+    stages: WaveStages = dataclasses.field(default_factory=WaveStages)
     _mats: Optional[list] = dataclasses.field(default=None, repr=False)
 
     @classmethod
@@ -1626,21 +1668,26 @@ def _wave_result(store, vids: Sequence[int], *,
         # single launch; don't build+pin a whole-store superblock for it
         return WaveResult.from_mats(_perpart_fallback(
             store, vids, stats, use_kernel, density_threshold))
+    stages = WaveStages()
     if sb is None:
-        sb, _ = get_superblock(store, max_bytes=max_bytes)
+        t0 = time.perf_counter()
+        with obs.span("checkout.pin"):
+            sb, _ = get_superblock(store, max_bytes=max_bytes)
+        stages.pin_s += time.perf_counter() - t0
         if sb is None:          # refused (store forbade caching): perpart
             return WaveResult.from_mats(_perpart_fallback(
                 store, vids, stats, use_kernel, density_threshold))
     part, _, dt = _gather_off_superblock(
-        store, vids, sb, use_kernel=True,
+        store, vids, sb, stages, use_kernel=True,
         density_threshold=density_threshold, want_density=stats is not None,
         defer=defer)
     if stats:
         stats.record(vids, *dt)
-    return WaveResult(n=len(vids), parts=[part])
+    return WaveResult(n=len(vids), parts=[part], stages=stages)
 
 
-def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock, *,
+def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock,
+                           stages: WaveStages, *,
                            use_kernel: bool, density_threshold: float,
                            want_density: bool = False, defer: bool = False
                            ) -> tuple[_WavePart, bool, Optional[tuple]]:
@@ -1655,7 +1702,8 @@ def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock, *,
     rlist pass), else None.  ``defer=True`` launches the jitted gather on
     the ``_wave_launcher`` worker so the call returns with the kernel in
     flight even on inline-dispatch backends; planning and the ``device()``
-    pin stay on this thread."""
+    pin stay on this thread.  The kernel tier adds its plan, first-upload
+    and launch seconds (and the upload's bytes) to ``stages``."""
     idxs = list(range(len(gvids)))
     if not use_kernel:
         rebased, _ = _rebase_wave(store, gvids, sb)
@@ -1664,29 +1712,46 @@ def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock, *,
             if want_density else None
         return _WavePart(idxs=idxs, mats=_fused_host_gather(
             sb.host[:, :sb.d], rebased)), False, dt
-    wp = plan_wave_cached(store, gvids, sb,
-                          density_threshold=density_threshold)
+    t0 = time.perf_counter()
+    with obs.span("checkout.plan", vids=len(gvids)):
+        wp = plan_wave_cached(store, gvids, sb,
+                              density_threshold=density_threshold)
+    plan_s = time.perf_counter() - t0
     dt = _plan_mode_density(wp.plan) if want_density else None
     if wp.n_tiles == 0:
+        stages.plan_s += plan_s
         empty = np.zeros((0, sb.d), dtype=sb.host.dtype)
         return _WavePart(idxs=idxs, mats=[empty for _ in gvids]), False, dt
     from ..kernels import ops as K
-    dev = sb.device()           # upload/pin on the CALLER's thread
+    pin_s, up = 0.0, 0
+    if sb._device is None:      # upload/pin on the CALLER's thread
+        t0, up = time.perf_counter(), sb.bytes_uploaded
+        with obs.span("checkout.pin", bytes=int(sb.host.nbytes)):
+            sb.device()
+        pin_s, up = time.perf_counter() - t0, sb.bytes_uploaded - up
+    dev = sb.device()
     # fires after planning + upload, before the pallas_call: a retry finds
     # the plan memo and the pinned device copy intact and just relaunches
     fault_point("wave.launch", store)
-    if defer and _defer_via_worker(wp.n_tiles):
-        packed = _wave_launcher().submit(
-            K.checkout_wave, dev, wp.plan.starts, wp.plan.mode, wp.hi,
-            block_n=sb.block_n, row_lanes=sb.row_lanes)
-    else:
-        packed = K.checkout_wave(dev, wp.plan.starts, wp.plan.mode, wp.hi,
-                                 block_n=sb.block_n, row_lanes=sb.row_lanes)
+    t0 = time.perf_counter()
+    with obs.span("checkout.launch", tiles=wp.n_tiles):
+        if defer and _defer_via_worker(wp.n_tiles):
+            packed = _wave_launcher().submit(
+                K.checkout_wave, dev, wp.plan.starts, wp.plan.mode, wp.hi,
+                block_n=sb.block_n, row_lanes=sb.row_lanes)
+        else:
+            packed = K.checkout_wave(dev, wp.plan.starts, wp.plan.mode,
+                                     wp.hi, block_n=sb.block_n,
+                                     row_lanes=sb.row_lanes)
+    stages.launch_s += time.perf_counter() - t0
+    stages.plan_s += plan_s
+    stages.pin_s += pin_s
+    stages.h2d_bytes += up
     sb.launches += 1
     return _WavePart(idxs=idxs, packed=packed,
                      segments=[wp.segment(k, sb.block_n)
                                for k in range(len(gvids))],
-                     d=sb.d, width=sb.host.shape[1]), True, dt
+                     d=sb.d, width=sb.host.shape[1], stages=stages), True, dt
 
 
 def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
@@ -1730,15 +1795,20 @@ def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
     pins0, ev0 = mgr.pins, mgr.evictions
     protected = set(by_group)
     parts: list[_WavePart] = []
+    stages = WaveStages()
     for key, idxs in by_group.items():
-        sb = mgr.pin(key, protected=protected) if use_kernel \
-            else mgr.peek(key)
+        sb = mgr.peek(key)
+        if sb is None and use_kernel:
+            t0 = time.perf_counter()
+            with obs.span("checkout.pin", partitions=len(key)):
+                sb = mgr.pin(key, protected=protected)
+            stages.pin_s += time.perf_counter() - t0
         if sb is None:
             stragglers.extend(idxs)
             continue
         gvids = [vids[i] for i in idxs]
         part, launched, dt = _gather_off_superblock(
-            store, gvids, sb, use_kernel=use_kernel,
+            store, gvids, sb, stages, use_kernel=use_kernel,
             density_threshold=density_threshold,
             want_density=stats is not None, defer=defer)
         if launched:
@@ -1752,8 +1822,12 @@ def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
     if stragglers:
         stragglers.sort()
         svids = [vids[i] for i in stragglers]
-        mats = checkout_partitioned_perpart(store, svids,
-                                            use_kernel=use_kernel)
+        t0 = time.perf_counter()
+        with obs.span("checkout.stragglers", vids=len(svids)):
+            mats = checkout_partitioned_perpart(store, svids,
+                                                use_kernel=use_kernel,
+                                                stages=stages)
+        stages.straggler_s += time.perf_counter() - t0
         parts.append(_WavePart(idxs=list(stragglers), mats=list(mats)))
         if stats:
             d_s, t_s = _local_wave_density(store, svids, density_threshold)
@@ -1768,7 +1842,7 @@ def _grouped_wave(store, vids: Sequence[int], mgr: SuperblockGroups, *,
     mgr.groups_touched += report.groups_touched
     mgr.straggler_requests += len(stragglers)
     mgr.last_wave = report
-    return WaveResult(n=len(vids), parts=parts)
+    return WaveResult(n=len(vids), parts=parts, stages=stages)
 
 
 # ---------------------------------------------------- superblock migration --
@@ -2264,11 +2338,13 @@ def checkout_partitioned(store, vids: Sequence[int], *,
 
 
 def checkout_partitioned_perpart(store, vids: Sequence[int], *,
-                                 use_kernel: Optional[bool] = None
+                                 use_kernel: Optional[bool] = None,
+                                 stages: Optional[WaveStages] = None
                                  ) -> list[np.ndarray]:
     """Per-partition engine: one fused gather (one launch) per partition
     touched by the wave — the baseline the wave engine is benchmarked
-    against."""
+    against.  Each partition block the kernel uploads adds its bytes to
+    ``stages.h2d_bytes``."""
     vids = _validate_vids(store, vids)
     by_pid: dict[int, list[int]] = {}
     for i, v in enumerate(vids):
@@ -2277,7 +2353,8 @@ def checkout_partitioned_perpart(store, vids: Sequence[int], *,
     for pid, req_idx in by_pid.items():
         p = store.partitions[pid]
         rls = [p.local_rlist(vids[i]) for i in req_idx]
-        mats = checkout_rlists(p.block, rls, use_kernel=use_kernel)
+        mats = checkout_rlists(p.block, rls, use_kernel=use_kernel,
+                               stages=stages)
         for i, m in zip(req_idx, mats):
             out[i] = m
     return out  # type: ignore[return-value]

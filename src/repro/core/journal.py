@@ -65,6 +65,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from .faults import fault_point
 
 logger = logging.getLogger(__name__)
@@ -128,7 +129,7 @@ class Journal:
         truncated back to its pre-append length: a retry appends a clean
         frame, never a duplicate."""
         t0 = time.perf_counter()
-        with self._lock:
+        with self._lock, obs.span("journal.append", kind=kind):
             fault_point("journal.append", self._owner)
             rec = dict(payload)
             rec["kind"] = kind
@@ -141,7 +142,8 @@ class Journal:
                 self._write_frame(frame)
                 if sync:
                     fault_point("journal.fsync", self._owner)
-                    os.fsync(self._f.fileno())
+                    with obs.span("journal.fsync", bytes=len(frame)):
+                        os.fsync(self._f.fileno())
                     self.synced += 1
             except BaseException:
                 self._repair(start)

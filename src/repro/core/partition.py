@@ -24,13 +24,29 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from .graph import BipartiteGraph
 
 logger = logging.getLogger(__name__)
+
+
+@dataclasses.dataclass
+class IngestWaveReport:
+    """Host seconds of ONE ``commit_many`` wave, by stage (the stages never
+    nest; the serve layer sums them into ``CheckoutStats`` when the commit
+    wave lands)."""
+    commits: int = 0
+    stage_s: float = 0.0      # STAGE 1 + 2: delta extraction, CSR and data
+                              # concatenation, partition rebuilds
+    journal_s: float = 0.0    # the ``commit.batch`` append, encode to fsync
+                              # (the wave's delta of ``Journal.write_s``)
+    refresh_s: float = 0.0    # refresh_superblocks_after_commit: touched
+                              # segment rebuild, delta upload, segment_append
 
 
 @dataclasses.dataclass
@@ -64,6 +80,7 @@ class PartitionedCVD:
     route through the per-partition engine instead of OOMing."""
 
     superblock_max_bytes: Optional[int] = None
+    last_ingest: Optional[IngestWaveReport] = None   # newest commit_many wave
 
     def __init__(self, graph: BipartiteGraph, data: np.ndarray, assignment: np.ndarray):
         self.graph = graph
@@ -264,136 +281,151 @@ class PartitionedCVD:
         ``ingest.extract`` at entry (nothing staged), ``ingest.commit`` at
         the stage->journal boundary (store and journal untouched).
 
+        A landed wave leaves its host seconds by stage in
+        ``self.last_ingest`` (``IngestWaveReport``).
+
         Returns the new vids, ``[vid0, vid0 + K)``."""
+        commits = [dict(c) for c in commits]
+        if not commits:
+            return []
+        with obs.span("ingest.commit_many", commits=len(commits)):
+            return self._commit_many(commits, extend_superblocks)
+
+    def _commit_many(self, commits: list[dict],
+                     extend_superblocks: bool) -> list[int]:
         from .checkout import refresh_superblocks_after_commit
         from .datamodels import diff_against_parents
         from .faults import fault_point
         from .graph import intersect_size
         from .journal import _enc, get_journal
-        commits = [dict(c) for c in commits]
-        if not commits:
-            return []
         fault_point("ingest.extract", self)
-        vid0 = int(self.graph.n_versions)
-        n0 = int(self.graph.n_records)
-        width = self.data.shape[1]
-        # -- STAGE 1: per-commit delta extraction against (possibly staged)
-        #    parents; the store is read, never written --------------------
-        data_blocks: list[np.ndarray] = [self.data]
-        n_cur = n0
-        cat_cache: list[Optional[np.ndarray]] = [None]
+        t0 = time.perf_counter()
+        with obs.span("ingest.stage", commits=len(commits)):
+            vid0 = int(self.graph.n_versions)
+            n0 = int(self.graph.n_records)
+            width = self.data.shape[1]
+            # -- STAGE 1: per-commit delta extraction against (possibly
+            #    staged) parents; the store is read, never written ----------
+            data_blocks: list[np.ndarray] = [self.data]
+            n_cur = n0
+            cat_cache: list[Optional[np.ndarray]] = [None]
 
-        def staged_rows(rids: np.ndarray) -> np.ndarray:
-            # gather parent rows across the staged blocks; concatenate
-            # lazily and only re-concatenate after the staged data grew
-            if len(data_blocks) == 1:
-                return self.data[rids]
-            if cat_cache[0] is None or len(cat_cache[0]) < n_cur:
-                cat_cache[0] = np.concatenate(data_blocks, axis=0)
-            return cat_cache[0][rids]
+            def staged_rows(rids: np.ndarray) -> np.ndarray:
+                # gather parent rows across the staged blocks; concatenate
+                # lazily and only re-concatenate after the staged data grew
+                if len(data_blocks) == 1:
+                    return self.data[rids]
+                if cat_cache[0] is None or len(cat_cache[0]) < n_cur:
+                    cat_cache[0] = np.concatenate(data_blocks, axis=0)
+                return cat_cache[0][rids]
 
-        assignment = self.assignment.copy()
-        rlists: list[np.ndarray] = []
-        parents: list[Optional[int]] = []
-        pids: list[int] = []
-        new_blocks: list[Optional[np.ndarray]] = []
-        for i, c in enumerate(commits):
-            vid = vid0 + i
-            parent = c.get("parent")
-            if parent is not None:
-                parent = int(parent)
-                if not 0 <= parent < vid:
-                    raise ValueError(
-                        f"commit #{i}: parent vid {parent} out of range "
-                        f"[0, {vid}) (earlier wave entries are allowed)")
-            if c.get("table") is not None:
-                if parent is None:
-                    raise ValueError(
-                        f"commit #{i}: table-form commits need a parent "
-                        f"to diff against")
-                table = np.ascontiguousarray(
-                    np.asarray(c["table"], dtype=self.data.dtype))
-                if table.ndim != 2 or table.shape[1] != width:
-                    raise ValueError(
-                        f"commit #{i}: table shape {table.shape} does not "
-                        f"match the base data width {width}")
-                p_rids = (self.graph.rlist(parent) if parent < vid0
-                          else rlists[parent - vid0])
-                matched, new_rows = diff_against_parents(
-                    table, staged_rows(p_rids), p_rids)
-                if len(new_rows) == 0:
-                    new_rows = None
-                k = 0 if new_rows is None else len(new_rows)
-                rlist = np.unique(np.concatenate(
-                    [matched, n_cur + np.arange(k, dtype=np.int64)]))
-            else:
-                rlist = np.unique(np.asarray(c["rlist"], dtype=np.int64))
-                new_rows = c.get("new_rows")
-                if new_rows is not None and len(new_rows) == 0:
-                    new_rows = None
-                if new_rows is not None:
-                    new_rows = np.ascontiguousarray(
-                        np.asarray(new_rows, dtype=self.data.dtype))
-                    if new_rows.ndim != 2 or new_rows.shape[1] != width:
+            assignment = self.assignment.copy()
+            rlists: list[np.ndarray] = []
+            parents: list[Optional[int]] = []
+            pids: list[int] = []
+            new_blocks: list[Optional[np.ndarray]] = []
+            for i, c in enumerate(commits):
+                vid = vid0 + i
+                parent = c.get("parent")
+                if parent is not None:
+                    parent = int(parent)
+                    if not 0 <= parent < vid:
                         raise ValueError(
-                            f"commit #{i}: new_rows shape {new_rows.shape} "
-                            f"does not match the base data width {width}")
-                k = 0 if new_rows is None else len(new_rows)
-                if len(rlist) and (rlist[0] < 0 or rlist[-1] >= n_cur + k):
-                    raise ValueError(
-                        f"commit #{i}: rlist references rid "
-                        f"{int(rlist[-1])} outside [0, {n_cur + k})")
-            pid = c.get("pid")
-            if pid is None:
-                pid = (int(assignment[parent]) if parent is not None
-                       else int(assignment.max()) + 1
-                       if len(assignment) else 0)
-            pid = int(pid)
-            if new_rows is not None:
-                data_blocks.append(new_rows)
-                n_cur += k
-            assignment = np.append(assignment, pid)
-            rlists.append(rlist)
-            parents.append(parent)
-            pids.append(pid)
-            new_blocks.append(new_rows)
-        # -- STAGE 2: one bulk CSR append + one rebuild per touched
-        #    partition label ---------------------------------------------
-        K = len(commits)
-        counts = np.array([len(r) for r in rlists], dtype=np.int64)
-        indptr = np.concatenate([
-            self.graph.indptr,
-            self.graph.indptr[-1] + np.cumsum(counts)])
-        indices = np.concatenate([self.graph.indices] + rlists)
-        data = (data_blocks[0] if len(data_blocks) == 1
-                else np.concatenate(data_blocks, axis=0))
-        staged_graph = BipartiteGraph(indptr=indptr, indices=indices,
-                                      n_records=n_cur)
-        slot_of = {p.pid: s for s, p in enumerate(self.partitions)}
-        staged_parts: dict[int, Partition] = {}
-        slot_for_pid: dict[int, int] = {}
-        old_grids: dict[int, np.ndarray] = {}
-        next_slot = len(self.partitions)
-        for pid in sorted(set(pids)):
-            vids = np.flatnonzero(assignment == pid)
-            staged_parts[pid] = build_partition(staged_graph, data, pid, vids)
-            s = slot_of.get(pid)
-            if s is None:
-                s, next_slot = next_slot, next_slot + 1
-                old_grids[s] = np.zeros(0, np.int64)
-            else:
-                old_grids[s] = self.partitions[s].grids
-            slot_for_pid[pid] = s
-        edge_ws = [intersect_size(staged_graph.rlist(p), rlists[i])
-                   if (p := parents[i]) is not None else 0
-                   for i in range(K)]
+                            f"commit #{i}: parent vid {parent} out of range "
+                            f"[0, {vid}) (earlier wave entries are allowed)")
+                if c.get("table") is not None:
+                    if parent is None:
+                        raise ValueError(
+                            f"commit #{i}: table-form commits need a parent "
+                            f"to diff against")
+                    table = np.ascontiguousarray(
+                        np.asarray(c["table"], dtype=self.data.dtype))
+                    if table.ndim != 2 or table.shape[1] != width:
+                        raise ValueError(
+                            f"commit #{i}: table shape {table.shape} does not "
+                            f"match the base data width {width}")
+                    p_rids = (self.graph.rlist(parent) if parent < vid0
+                              else rlists[parent - vid0])
+                    matched, new_rows = diff_against_parents(
+                        table, staged_rows(p_rids), p_rids)
+                    if len(new_rows) == 0:
+                        new_rows = None
+                    k = 0 if new_rows is None else len(new_rows)
+                    rlist = np.unique(np.concatenate(
+                        [matched, n_cur + np.arange(k, dtype=np.int64)]))
+                else:
+                    rlist = np.unique(np.asarray(c["rlist"], dtype=np.int64))
+                    new_rows = c.get("new_rows")
+                    if new_rows is not None and len(new_rows) == 0:
+                        new_rows = None
+                    if new_rows is not None:
+                        new_rows = np.ascontiguousarray(
+                            np.asarray(new_rows, dtype=self.data.dtype))
+                        if new_rows.ndim != 2 or new_rows.shape[1] != width:
+                            raise ValueError(
+                                f"commit #{i}: new_rows shape "
+                                f"{new_rows.shape} does not match the base "
+                                f"data width {width}")
+                    k = 0 if new_rows is None else len(new_rows)
+                    if len(rlist) and (rlist[0] < 0 or rlist[-1] >= n_cur + k):
+                        raise ValueError(
+                            f"commit #{i}: rlist references rid "
+                            f"{int(rlist[-1])} outside [0, {n_cur + k})")
+                pid = c.get("pid")
+                if pid is None:
+                    pid = (int(assignment[parent]) if parent is not None
+                           else int(assignment.max()) + 1
+                           if len(assignment) else 0)
+                pid = int(pid)
+                if new_rows is not None:
+                    data_blocks.append(new_rows)
+                    n_cur += k
+                assignment = np.append(assignment, pid)
+                rlists.append(rlist)
+                parents.append(parent)
+                pids.append(pid)
+                new_blocks.append(new_rows)
+            # -- STAGE 2: one bulk CSR append + one rebuild per touched
+            #    partition label -----------------------------------------
+            K = len(commits)
+            counts = np.array([len(r) for r in rlists], dtype=np.int64)
+            indptr = np.concatenate([
+                self.graph.indptr,
+                self.graph.indptr[-1] + np.cumsum(counts)])
+            indices = np.concatenate([self.graph.indices] + rlists)
+            data = (data_blocks[0] if len(data_blocks) == 1
+                    else np.concatenate(data_blocks, axis=0))
+            staged_graph = BipartiteGraph(indptr=indptr, indices=indices,
+                                          n_records=n_cur)
+            slot_of = {p.pid: s for s, p in enumerate(self.partitions)}
+            staged_parts: dict[int, Partition] = {}
+            slot_for_pid: dict[int, int] = {}
+            old_grids: dict[int, np.ndarray] = {}
+            next_slot = len(self.partitions)
+            for pid in sorted(set(pids)):
+                vids = np.flatnonzero(assignment == pid)
+                staged_parts[pid] = build_partition(staged_graph, data, pid,
+                                                    vids)
+                s = slot_of.get(pid)
+                if s is None:
+                    s, next_slot = next_slot, next_slot + 1
+                    old_grids[s] = np.zeros(0, np.int64)
+                else:
+                    old_grids[s] = self.partitions[s].grids
+                slot_for_pid[pid] = s
+            edge_ws = [intersect_size(staged_graph.rlist(p), rlists[i])
+                       if (p := parents[i]) is not None else 0
+                       for i in range(K)]
+        stage_s = time.perf_counter() - t0
         # fires at the stage->journal boundary: store AND journal are both
         # still untouched, so a plain retry re-stages from scratch
         fault_point("ingest.commit", self)
         j = get_journal(self)
+        journal_s = 0.0
         if j is not None:
             # group commit: ONE fsynced record covers the whole wave —
             # replay applies all K commits or none of them
+            w0 = j.write_s
             j.append("commit.batch", {
                 "vid0": vid0,
                 "commits": [{
@@ -406,6 +438,7 @@ class PartitionedCVD:
                     for i in range(K)],
                 "epoch_after": int(self.epoch) + 1,
                 "n_versions_after": vid0 + K}, sync=True)
+            journal_s = j.write_s - w0
         # -- COMMIT: pure field swaps (nothing below can fail) --------------
         self.data = data
         self.graph.indptr = indptr
@@ -425,13 +458,18 @@ class PartitionedCVD:
         for i in range(K):
             _log_commit(self, vid0 + i, parents[i], edge_ws[i],
                         int(counts[i]))
-        try:
-            refresh_superblocks_after_commit(
-                self, old_grids, extend=extend_superblocks)
-        except Exception:
-            logger.warning("post-ingest superblock refresh failed; stale "
-                           "device copies will lapse on next access",
-                           exc_info=True)
+        t0 = time.perf_counter()
+        with obs.span("ingest.refresh"):
+            try:
+                refresh_superblocks_after_commit(
+                    self, old_grids, extend=extend_superblocks)
+            except Exception:
+                logger.warning("post-ingest superblock refresh failed; "
+                               "stale device copies will lapse on next "
+                               "access", exc_info=True)
+        self.last_ingest = IngestWaveReport(
+            commits=K, stage_s=stage_s, journal_s=journal_s,
+            refresh_s=time.perf_counter() - t0)
         return list(range(vid0, vid0 + K))
 
     def apply_migration(self, plan: "MigrationPlan") -> None:

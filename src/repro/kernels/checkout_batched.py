@@ -231,6 +231,7 @@ def checkout_wave(data: jax.Array, starts: jax.Array, mode: jax.Array,
         _make_wave_kernel(block_n, row_lanes),
         [starts.astype(jnp.int32), mode.astype(jnp.int32),
          hi.astype(jnp.int32)], [data],
+        name="checkout_wave",
         n_tiles=t, block_rows=block_n * row_lanes, dtype=data.dtype,
         scratch_shapes=[pltpu.SemaphoreType.DMA((block_n,))],
         interpret=interpret)

@@ -27,6 +27,26 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _fresh_datastack(fn, *args, **kwargs):
+    """``fn(*args, **kwargs)``, run on an interpreter data stack of its own.
+
+    CPython 3.11+ keeps Python frames in 16 KB "data stack" chunks and
+    frees a chunk the moment the frame at its base returns.  Tracing and
+    lowering a kernel of a new shape is a deep recursion of small calls:
+    where a hot call lands on a chunk boundary, every such call maps and
+    unmaps a chunk, so the lowering's cost depends on how deep the caller
+    happens to be (one gather lowered in 150 ms from one serving call
+    path and in 267 ms from a path a few frames deeper, TPU v5e host).
+    This function's frame declares a 64 K-slot stack it never uses, so
+    every call opens one 1 MB chunk and the whole trace, lowering and
+    compile run inside it, at the same cost from any caller."""
+    return fn(*args, **kwargs)
+
+
+_fresh_datastack.__code__ = _fresh_datastack.__code__.replace(
+    co_stacksize=1 << 16)
+
+
 def _pad_axis(x: jax.Array, mult: int, axis: int) -> jax.Array:
     n = x.shape[axis]
     pad = (-n) % mult
@@ -132,7 +152,7 @@ def checkout_gather_tiled(data, rids, *, block_n: int = _cg.DEFAULT_BN,
 
 def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
                      density_threshold: float = 0.05,
-                     interpret: bool | None = None):
+                     interpret: bool | None = None, stages=None):
     """Fused multi-version checkout: K rlists, one wave-kernel gather.
 
     Plans the concatenation of the rlists with ``plan_batched`` — per-tile
@@ -147,6 +167,9 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
     only fire on exactly-consecutive chunks), matching the host fallback and
     the NumPy oracle.  Canonical sorted-unique rlists get the dense fast
     path.
+
+    ``stages`` (a ``core.checkout.WaveStages``), when given, has the bytes
+    of a host block's upload added to its ``h2d_bytes``.
 
     Returns (list of (n_k, D) arrays in request order, BatchedPlan).
     """
@@ -166,9 +189,12 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
     # run DMA is statically block_n rows); pad rows up to the tile — runs
     # only fire on consecutive REAL rids, so the pad rows are never read
     lane_data, w = _lane_rows(data, min_rows=block_n)
-    packed = _cb.checkout_wave(
-        lane_data, jnp.asarray(plan.starts), jnp.asarray(plan.mode),
-        jnp.full(plan.n_tiles, r, jnp.int32), block_n=block_n, row_lanes=w,
+    if stages is not None and not isinstance(data, jax.Array):
+        stages.h2d_bytes += int(lane_data.nbytes)
+    packed = _fresh_datastack(
+        _cb.checkout_wave, lane_data, jnp.asarray(plan.starts),
+        jnp.asarray(plan.mode), jnp.full(plan.n_tiles, r, jnp.int32),
+        block_n=block_n, row_lanes=w,
         interpret=not _on_tpu() if interpret is None else interpret)
     packed = np.asarray(packed).reshape(-1, w * LANES)[:, :d]
     return [packed[plan.segment(k, block_n)] for k in range(len(rls))], plan
@@ -189,8 +215,9 @@ def checkout_wave(data, starts, mode, hi, *, block_n: int = _cg.DEFAULT_BN,
     """
     data = jnp.asarray(data)
     _check_lane_rows(data, "superblock")
-    return _cb.checkout_wave(
-        data, jnp.asarray(starts), jnp.asarray(mode), jnp.asarray(hi),
+    return _fresh_datastack(
+        _cb.checkout_wave, data, jnp.asarray(starts), jnp.asarray(mode),
+        jnp.asarray(hi),
         block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
@@ -207,8 +234,8 @@ def segment_move(src, delta, sel, starts, *, block_n: int = _cg.DEFAULT_BN,
     delta = jnp.asarray(delta)
     _check_lane_rows(src, "superblock")
     _check_lane_rows(delta, "delta")
-    return _sm.segment_move(
-        src, delta, jnp.asarray(sel), jnp.asarray(starts),
+    return _fresh_datastack(
+        _sm.segment_move, src, delta, jnp.asarray(sel), jnp.asarray(starts),
         block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
@@ -226,8 +253,8 @@ def segment_append(src, delta, sel, starts, *,
     delta = jnp.asarray(delta)
     _check_lane_rows(src, "superblock")
     _check_lane_rows(delta, "delta")
-    return _sa.segment_append(
-        src, delta, jnp.asarray(sel), jnp.asarray(starts),
+    return _fresh_datastack(
+        _sa.segment_append, src, delta, jnp.asarray(sel), jnp.asarray(starts),
         block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 

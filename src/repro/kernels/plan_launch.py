@@ -38,7 +38,7 @@ def launch_ranges(n_tiles: int, bytes_per_tile: int) -> list[tuple[int, int]]:
     return [(t0, min(per, n_tiles - t0)) for t0 in range(0, n_tiles, per)]
 
 
-def planned_tile_call(kernel, scalars, operands, *, n_tiles: int,
+def planned_tile_call(kernel, scalars, operands, *, name: str, n_tiles: int,
                       block_rows: int, dtype, scratch_shapes,
                       interpret: bool):
     """Run ``kernel`` over tiles ``0..n_tiles-1`` in SMEM-sized launches.
@@ -50,6 +50,8 @@ def planned_tile_call(kernel, scalars, operands, *, n_tiles: int,
     scalars:  int32 per-tile plan arrays, each with a whole number of
               entries per tile (prefetched to SMEM per launch).
     operands: HBM inputs (``memory_space=ANY``), read by manual DMA.
+    name:     the kernel's name in the compiled program (every launch of
+              the split carries it), so a trace tells the kernels apart.
     Returns the ``(n_tiles * block_rows, LANES)`` output; tile t is rows
     ``[t * block_rows, (t + 1) * block_rows)``."""
     per_tile = [int(s.shape[0]) // n_tiles for s in scalars]
@@ -72,7 +74,7 @@ def planned_tile_call(kernel, scalars, operands, *, n_tiles: int,
             functools.partial(_drop_alias, kernel, n_fixed, out is not None),
             grid_spec=spec, out_shape=out_shape,
             input_output_aliases={} if out is None else {len(args) - 1: 0},
-            interpret=interpret,
+            interpret=interpret, name=name,
         )(*args)
     return out
 

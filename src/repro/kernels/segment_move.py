@@ -89,6 +89,7 @@ def segment_move(src: jax.Array, delta: jax.Array, sel: jax.Array,
     return planned_tile_call(
         _make_kernel(block_n, row_lanes),
         [sel.astype(jnp.int32), starts.astype(jnp.int32)], [src, delta],
+        name="segment_move",
         n_tiles=sel.shape[0], block_rows=block_n * row_lanes,
         dtype=src.dtype, scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
         interpret=interpret)

@@ -94,9 +94,10 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from ..core.checkout import (_default_use_kernel, _validate_vids,
-                             checkout_partitioned, get_superblock,
-                             get_superblock_groups)
+from .. import obs
+from ..core.checkout import (WaveStages, _default_use_kernel,
+                             _validate_vids, checkout_partitioned,
+                             get_superblock, get_superblock_groups)
 from ..core.faults import acquire_read_lease, fault_point, read_leases
 
 logger = logging.getLogger(__name__)
@@ -182,9 +183,26 @@ class CheckoutStats:
     commit_deferrals: int = 0      # write waves a lease-drain timeout
                                    # deferred (re-queued, retried at the
                                    # next flush)
-    # sliding window (deque, maxlen) — unbounded growth would leak on a
-    # long-running server; `requests` keeps the all-time count.  Append via
-    # ``record_latency`` (it invalidates the percentile cache).
+    # host stages, each second counted once (the stages never nest): a
+    # read wave's (core.checkout.WaveStages) dispatch stages summed at
+    # dispatch and its delivery stages at delivery, a commit wave's
+    # (core.partition.IngestWaveReport) when it lands
+    plan_s: float = 0.0            # wave plans (memo lookup, plan on a miss)
+    launch_s: float = 0.0          # jitted gather calls: trace, lower and
+                                   # compile of a new shape, enqueue
+    pin_s: float = 0.0             # superblock pins: build, evict, upload
+    straggler_s: float = 0.0       # per-partition straggler batches
+    device_wait_s: float = 0.0     # waiting for a gather before its copy
+    d2h_s: float = 0.0             # device→host copies + per-vid split
+    h2d_bytes: int = 0             # superblock + straggler uploads
+    d2h_bytes: int = 0             # packed gathers copied to the host
+    ingest_stage_s: float = 0.0    # commit_many STAGE 1 + 2
+    journal_s: float = 0.0         # commit.batch append, encode to fsync
+    refresh_s: float = 0.0         # post-commit superblock refresh
+    # CHECKOUT latency, server submit to delivery (commits are not stamped
+    # here): a sliding window (deque, maxlen) — unbounded growth would leak
+    # on a long-running server; `requests` keeps the all-time count.
+    # Append via ``record_latency`` (it invalidates the percentile cache).
     ticket_latency_s: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
     _lat_cache: Optional[tuple] = dataclasses.field(
@@ -230,6 +248,7 @@ class _InflightWave:
     uniq: list                     # sorted unique vids the gather ran over
     handle: object                 # core.checkout.WaveResult
     group_delta: tuple             # group-manager counter delta at dispatch
+    wave: int                      # stats.waves at dispatch: the wave's id
     lease: object                  # core.faults.ReadLease pinning the epoch
                                    # the wave planned against (idempotent
                                    # release; owns the _inflight_waves count)
@@ -447,6 +466,13 @@ class BatchedCheckoutServer:
         ``pipeline=False``.  Every result is also retained for
         ``result(ticket)`` — ticket-oriented callers are mode-agnostic."""
         self._check_open()
+        ids = {"wave": self.stats.waves}
+        if self._pending_writes:
+            ids["commit_wave"] = self.stats.commit_waves
+        with obs.span("serve.flush", **ids):
+            return self._flush()
+
+    def _flush(self) -> list[np.ndarray]:
         self._journal_watermark()
         # land the write wave FIRST: the read wave detached below then
         # plans against (and serves) the post-commit epoch.  A failed or
@@ -482,7 +508,9 @@ class BatchedCheckoutServer:
             # the plan.  A failed dispatch releases it (nothing in flight).
             lease = acquire_read_lease(self.store)
             try:
-                handle = self._dispatch(uniq)
+                with obs.span("serve.dispatch", wave=self.stats.waves,
+                              vids=len(uniq)):
+                    handle = self._dispatch(uniq)
             except BaseException:
                 # a failed gather must not destroy the coalesced wave:
                 # re-queue every request so the tickets stay serviceable,
@@ -497,7 +525,9 @@ class BatchedCheckoutServer:
                 tickets=wave,
                 ticket_ids=frozenset(t for t, _, _ in wave),
                 uniq=uniq, handle=handle, lease=lease,
-                group_delta=tuple(b - a for a, b in zip(g0, g1)))
+                group_delta=tuple(b - a for a, b in zip(g0, g1)),
+                wave=self.stats.waves)
+            self._apply_stages(handle, WaveStages.DISPATCH)
             self.stats.waves += 1
             self.stats.requests += len(wave)
             self.stats.unique_versions += len(uniq)
@@ -675,10 +705,8 @@ class BatchedCheckoutServer:
             self._deadline_armed = False
             self.stats.requeues += 1
             raise
-        done = self._clock()
         self._results.update(zip((t for t, _, _ in batch),
                                  (np.int64(v) for v in vids)))
-        self.stats.record_latencies([done - t0 for _, _, t0 in batch])
         if len(self._results) > RETAIN_RESULTS:
             for t in list(self._results):
                 if len(self._results) <= RETAIN_RESULTS:
@@ -687,6 +715,11 @@ class BatchedCheckoutServer:
                     del self._results[t]
         self.stats.commit_waves += 1
         self.stats.commits_ingested += len(batch)
+        report = getattr(self.store, "last_ingest", None)
+        if report is not None:
+            self.stats.ingest_stage_s += report.stage_s
+            self.stats.journal_s += report.journal_s
+            self.stats.refresh_s += report.refresh_s
         return vids
 
     def _commit(self, commits: list) -> list[int]:
@@ -748,6 +781,10 @@ class BatchedCheckoutServer:
         """The deliver stage for one (already detached) wave.  A delivery
         failure re-queues the wave's tickets and rolls back its dispatch
         accounting, exactly like a dispatch failure."""
+        with obs.span("serve.deliver", wave=wave.wave):
+            return self._deliver(wave)
+
+    def _deliver(self, wave: _InflightWave) -> list[np.ndarray]:
         try:
             mats = self._materialize(wave)
         except BaseException:
@@ -785,6 +822,7 @@ class BatchedCheckoutServer:
         # wave's dispatch captured — a concurrent in-flight dispatch can
         # never bleed into it
         self._apply_group_delta(wave.group_delta)
+        self._apply_stages(wave.handle, WaveStages.DELIVERY)
         # the density trigger runs BETWEEN DELIVERED waves only: when
         # flush() already put the next wave in flight, migrating now would
         # race its launched kernel — observe() runs at THAT wave's
@@ -817,6 +855,14 @@ class BatchedCheckoutServer:
             return _GROUP_COUNTER_ZERO
         return (mgr.waves, mgr.groups_touched, mgr.launches,
                 mgr.evictions, mgr.straggler_requests)
+
+    def _apply_stages(self, handle, names: tuple) -> None:
+        st = getattr(handle, "stages", None)
+        if st is None:
+            return
+        for name in names:
+            setattr(self.stats, name,
+                    getattr(self.stats, name) + getattr(st, name))
 
     def _apply_group_delta(self, d: tuple) -> None:
         self.stats.group_waves += d[0]

@@ -109,3 +109,12 @@ def test_version_aggregate_count_mode(rng):
     counts = np.asarray(ops.version_aggregate(bm, np.ones(r, np.float32)))
     for v in range(nv):
         assert counts[v] == len(rlists[v])
+
+
+def test_fresh_datastack_passes_the_call_through_on_a_chunk_of_its_own():
+    """The launch wrapper returns ``fn``'s result unchanged, and its frame
+    declares a stack far deeper than the interpreter's 16 KB data-stack
+    chunk, so every call opens a chunk of its own for the trace and
+    lowering below it."""
+    assert ops._fresh_datastack(lambda a, *, b: (a, b), 2, b=3) == (2, 3)
+    assert ops._fresh_datastack.__code__.co_stacksize * 8 >= 32 * 16 * 1024
