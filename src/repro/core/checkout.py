@@ -20,7 +20,9 @@ Data-flow map (kernels -> core -> query/serve)::
       │                  usually one), all writing one output, no matter
       │                  how many partitions the wave touches (run
       │                  DMAs where the rlist is dense, row DMAs where
-      │                  scattered; the ``hi`` bound is checked on device)
+      │                  scattered; the ``hi`` bound is checked on device),
+      │                  launched at a tile count from a fixed ladder
+      │                  (``ops.launch_tiles``) so waves share compiles
       │    host path:    one np.take over the rebased concatenation when a
       │                  superblock is already cached; per-partition np.takes
       │                  otherwise (numpy pays no launch cost, so host-only
@@ -506,7 +508,8 @@ class WaveStages:
     ``DELIVERY`` ones; the serve layer sums each half into
     ``serve.checkout.CheckoutStats`` when that half of the wave is done."""
     DISPATCH: ClassVar[tuple] = ("plan_s", "launch_s", "pin_s",
-                                 "straggler_s", "h2d_bytes")
+                                 "straggler_s", "h2d_bytes", "tiles",
+                                 "pad_tiles")
     DELIVERY: ClassVar[tuple] = ("device_wait_s", "d2h_s", "d2h_bytes")
     plan_s: float = 0.0         # plan_wave_cached, per gather
     launch_s: float = 0.0       # the jitted gather calls: trace, lower and
@@ -515,6 +518,9 @@ class WaveStages:
                                 # first upload
     straggler_s: float = 0.0    # the per-partition straggler batch
     h2d_bytes: int = 0          # superblock uploads + straggler partitions
+    tiles: int = 0              # BN-row tiles the launched gathers planned
+    pad_tiles: int = 0          # tiles the launch ladder added to those
+                                # (``kernels.ops.launch_tiles``)
     device_wait_s: float = 0.0  # blocking until each packed gather is done
     d2h_s: float = 0.0          # device→host copies and per-vid splits
     d2h_bytes: int = 0          # packed gathers copied to the host
@@ -1703,7 +1709,8 @@ def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock,
     the ``_wave_launcher`` worker so the call returns with the kernel in
     flight even on inline-dispatch backends; planning and the ``device()``
     pin stay on this thread.  The kernel tier adds its plan, first-upload
-    and launch seconds (and the upload's bytes) to ``stages``."""
+    and launch seconds, the upload's bytes, and its planned and pad tiles
+    to ``stages``."""
     idxs = list(range(len(gvids)))
     if not use_kernel:
         rebased, _ = _rebase_wave(store, gvids, sb)
@@ -1744,6 +1751,8 @@ def _gather_off_superblock(store, gvids: Sequence[int], sb: Superblock,
                                      wp.hi, block_n=sb.block_n,
                                      row_lanes=sb.row_lanes)
     stages.launch_s += time.perf_counter() - t0
+    stages.tiles += wp.n_tiles
+    stages.pad_tiles += K.launch_tiles(wp.n_tiles) - wp.n_tiles
     stages.plan_s += plan_s
     stages.pin_s += pin_s
     stages.h2d_bytes += up

@@ -169,7 +169,9 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
     path.
 
     ``stages`` (a ``core.checkout.WaveStages``), when given, has the bytes
-    of a host block's upload added to its ``h2d_bytes``.
+    of a host block's upload added to its ``h2d_bytes``, and the planned
+    tiles and the ladder's pad tiles (``launch_tiles``) to ``tiles`` and
+    ``pad_tiles``.
 
     Returns (list of (n_k, D) arrays in request order, BatchedPlan).
     """
@@ -189,13 +191,14 @@ def checkout_batched(data, rlists, *, block_n: int = _cg.DEFAULT_BN,
     # run DMA is statically block_n rows); pad rows up to the tile — runs
     # only fire on consecutive REAL rids, so the pad rows are never read
     lane_data, w = _lane_rows(data, min_rows=block_n)
-    if stages is not None and not isinstance(data, jax.Array):
-        stages.h2d_bytes += int(lane_data.nbytes)
-    packed = _fresh_datastack(
-        _cb.checkout_wave, lane_data, jnp.asarray(plan.starts),
-        jnp.asarray(plan.mode), jnp.full(plan.n_tiles, r, jnp.int32),
-        block_n=block_n, row_lanes=w,
-        interpret=not _on_tpu() if interpret is None else interpret)
+    if stages is not None:
+        if not isinstance(data, jax.Array):
+            stages.h2d_bytes += int(lane_data.nbytes)
+        stages.tiles += plan.n_tiles
+        stages.pad_tiles += launch_tiles(plan.n_tiles) - plan.n_tiles
+    packed = _launch(lane_data, plan.starts, plan.mode,
+                     np.full(plan.n_tiles, r, np.int32), block_n=block_n,
+                     row_lanes=w, interpret=interpret)
     packed = np.asarray(packed).reshape(-1, w * LANES)[:, :d]
     return [packed[plan.segment(k, block_n)] for k in range(len(rls))], plan
 
@@ -207,18 +210,47 @@ def checkout_wave(data, starts, mode, hi, *, block_n: int = _cg.DEFAULT_BN,
     lane-row superblock ``(R * row_lanes, 128)`` — a few launches into one
     output at most (``plan_launch``).
 
-    Thin wrapper over ``checkout_batched.checkout_wave`` — the superblock
-    (``core.checkout.build_superblock``) is already padded and in the
-    lane-row layout, so nothing is padded here; this only resolves the
-    interpret/TPU mode.  Returns packed lane-rows: reshape on the host to
-    ``(-1, row_lanes * 128)`` for logical rows.
+    The superblock (``core.checkout.build_superblock``) is already padded
+    and in the lane-row layout; only the plan is padded here, to
+    ``launch_tiles(T)`` tiles.  Returns packed lane-rows: reshape on the
+    host to ``(-1, row_lanes * 128)`` for logical rows, of which the first
+    ``T * block_n`` are the plan's (the pad tiles' rows follow them).
     """
     data = jnp.asarray(data)
     _check_lane_rows(data, "superblock")
+    return _launch(data, starts, mode, hi, block_n=block_n,
+                   row_lanes=row_lanes, interpret=interpret)
+
+
+def launch_tiles(n: int) -> int:
+    """The tile count a serving gather of ``n`` planned tiles launches at.
+
+    The jitted gather compiles once per (array shape, tile count), and a
+    wave's planned count changes from wave to wave, so the launch rounds
+    it up to the next rung of a fixed ladder: every count up to 16, then
+    eight rungs per doubling (``m * 2**k``, 8 <= m < 16).  A launch pads
+    fewer than n/8 tiles, and the waves of one rung share one compile."""
+    step = 1 << max(0, (n - 1).bit_length() - 4)
+    return -(-n // step) * step
+
+
+def _launch(data, starts, mode, hi, *, block_n: int, row_lanes: int,
+            interpret: bool | None) -> jax.Array:
+    """``checkout_batched.checkout_wave`` over a lane-row ``data`` of at
+    least ``block_n`` rows, with the host plan padded to ``launch_tiles``
+    tiles.  A pad tile is a run DMA of rows ``[0, block_n)`` (start 0,
+    mode 1, bound ``block_n``) into output rows no plan segment reaches."""
+    t = len(mode)
+    pad = launch_tiles(t) - t
+    if pad:
+        starts = np.pad(np.asarray(starts, np.int32), (0, pad * block_n))
+        mode = np.pad(np.asarray(mode, np.int32), (0, pad),
+                      constant_values=1)
+        hi = np.pad(np.asarray(hi, np.int32), (0, pad),
+                    constant_values=block_n)
     return _fresh_datastack(
         _cb.checkout_wave, data, jnp.asarray(starts), jnp.asarray(mode),
-        jnp.asarray(hi),
-        block_n=block_n, row_lanes=row_lanes,
+        jnp.asarray(hi), block_n=block_n, row_lanes=row_lanes,
         interpret=not _on_tpu() if interpret is None else interpret)
 
 

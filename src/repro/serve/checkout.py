@@ -196,6 +196,8 @@ class CheckoutStats:
     d2h_s: float = 0.0             # device→host copies + per-vid split
     h2d_bytes: int = 0             # superblock + straggler uploads
     d2h_bytes: int = 0             # packed gathers copied to the host
+    tiles: int = 0                 # BN-row tiles the gathers planned
+    pad_tiles: int = 0             # tiles the launch ladder added to those
     ingest_stage_s: float = 0.0    # commit_many STAGE 1 + 2
     journal_s: float = 0.0         # commit.batch append, encode to fsync
     refresh_s: float = 0.0         # post-commit superblock refresh

@@ -9,8 +9,11 @@ import numpy as np
 import pytest
 
 from repro.core import generate
-from repro.core.checkout import (checkout_partitioned, checkout_rlists,
-                                 checkout_versions, checkout_versions_loop)
+from repro.core.checkout import (WaveStages, _gather_off_superblock,
+                                 build_superblock, checkout_partitioned,
+                                 checkout_rlists, checkout_versions,
+                                 checkout_versions_loop, checkout_wave)
+from repro.core.graph import BipartiteGraph
 from repro.core.datamodels import SplitByRlist
 from repro.core.partition import PartitionedCVD, single_partition
 from repro.core import query as Q
@@ -100,6 +103,126 @@ def test_checkout_wave_split_launches_bit_identical(rng, capped_launches,
     packed = np.asarray(split).reshape(-1, d)
     for k, want in enumerate(ref.gather_batched_ref(data, rls)):
         np.testing.assert_array_equal(packed[plan.segment(k, 8)], want)
+
+
+# ----------------------------------------------------------- launch ladder --
+@pytest.mark.parametrize("n", list(range(0, 18)) + [
+    31, 32, 33, 255, 256, 257, 4095, 4097, 13107, 30001, 47311, 65537,
+    123457, 1 << 20])
+def test_launch_tiles_ladder(n):
+    """The launch ladder: exact up to 8 (and to 16), never below the plan,
+    at most 1/8 more above that, monotone, and at most 8 rungs in any
+    doubling."""
+    got = ops.launch_tiles(n)
+    assert got >= n
+    assert ops.launch_tiles(n + 1) >= got
+    if n <= 8:
+        assert got == n
+    else:
+        assert got <= -(-n * 9 // 8)
+    if 16 <= n <= 1 << 17:
+        rungs = {ops.launch_tiles(m) for m in range(n + 1, 2 * n + 1)}
+        assert len(rungs) <= 9      # the rungs of (n, 2n], ends included
+    assert ops.launch_tiles(got) == got
+
+
+def _ladder_store(rng, p=4, r=640, d=12):
+    """16 versions over ``p`` partitions (v -> v % p); version v spans
+    1 + v // p BN-row tiles with a ragged tail, even versions one dense
+    run, odd ones scattered rows."""
+    rls = []
+    for v in range(16):
+        n = 8 * (1 + v // p) - v % 3
+        if v % 2 == 0:
+            s = int(rng.integers(0, r - n))
+            rls.append(np.arange(s, s + n, dtype=np.int64))
+        else:
+            rls.append(np.sort(rng.choice(r, n, replace=False))
+                       .astype(np.int64))
+    graph = BipartiteGraph.from_rlists(rls, n_records=r)
+    data = rng.integers(0, 1 << 20, (r, d)).astype(np.int32)
+    return PartitionedCVD(graph, data, np.arange(16) % p)
+
+
+def _pick_tiles(tiles, target):
+    """Keys of ``tiles`` (key -> BN-row tiles) whose tiles sum to
+    ``target``: most tiles first, any shortfall filled with repeats of the
+    smallest."""
+    pick, t = [], 0
+    for k in sorted(tiles, key=lambda k: -tiles[k]):
+        if t + tiles[k] <= target:
+            pick.append(k)
+            t += tiles[k]
+    small = min(tiles, key=tiles.get)
+    while t < target:
+        pick.append(small)
+        t += tiles[small]
+    assert t == target
+    return pick
+
+
+def _wave_of_tiles(store, target, vids):
+    return _pick_tiles({v: -(-len(store.checkout(v)) // 8) for v in vids},
+                       target)
+
+
+@pytest.mark.parametrize("offset", [-1, 0, 1])
+@pytest.mark.parametrize("path,rung", [("whole", 32), ("group", 24),
+                                       ("straggler", 36)])
+def test_gather_at_a_ladder_rung_is_bit_identical(rng, path, rung, offset):
+    """Waves whose plan ends just below, on and just above a rung of the
+    launch ladder, through a whole-store superblock, a group superblock
+    and a straggler batch: every block equals the host oracle, and the
+    wave's stages count the planned and the pad tiles."""
+    store = _ladder_store(rng)
+    target = rung + offset
+    pad = ops.launch_tiles(target) - target
+    assert (pad == 0) == (offset == 0)
+    stages = WaveStages()
+    if path == "straggler":
+        p = store.partitions[1]
+        rls = [p.local_rlist(int(v)) for v in p.vids]
+        pick = [rls[k] for k in _pick_tiles(
+            {k: -(-len(rl) // 8) for k, rl in enumerate(rls)}, target)]
+        outs, _ = ops.checkout_batched(p.block, pick, stages=stages)
+        wants = ref.gather_batched_ref(p.block, pick)
+    else:
+        if path == "whole":
+            vids = _wave_of_tiles(store, target, range(16))
+            res = checkout_wave(store, vids, use_kernel=True,
+                                device_out=True)
+            outs, stages = res.materialize(), res.stages
+        else:
+            sb = build_superblock(store, pids=(0, 1, 2))
+            vids = _wave_of_tiles(store, target,
+                                  [v for v in range(16) if v % 4 < 3])
+            part, launched, _ = _gather_off_superblock(
+                store, vids, sb, stages, use_kernel=True,
+                density_threshold=0.05)
+            assert launched
+            outs = part.split()
+        wants = [store.checkout(v) for v in vids]
+    assert (stages.tiles, stages.pad_tiles) == (target, pad)
+    assert len(outs) == len(wants)
+    for got, want in zip(outs, wants):
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_waves_of_one_rung_share_one_compiled_gather(rng):
+    """Two served waves of different tile counts on one rung compile the
+    gather once, and the server counts their planned and pad tiles."""
+    store = _ladder_store(rng)
+    srv = BatchedCheckoutServer(store, use_kernel=True)
+    waves = [_wave_of_tiles(store, t, range(16)) for t in (33, 35)]
+    assert ops.launch_tiles(33) == ops.launch_tiles(35) == 36
+    _cb.checkout_wave.clear_cache()
+    for vids in waves:
+        for v, m in zip(vids, srv.serve(vids)):
+            np.testing.assert_array_equal(m, store.checkout(v))
+    assert _cb.checkout_wave._cache_size() == 1
+    st = srv.stats
+    assert (st.tiles, st.pad_tiles) == (33 + 35, 3 + 1)
+    assert st.pad_tiles / (st.tiles + st.pad_tiles) <= 1 / 8
 
 
 def test_plan_batched_modes(rng):
