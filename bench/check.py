@@ -40,23 +40,32 @@ class Checks:
 
 
 def replay_commits(ref, acks, writers: dict, n0: int) -> bool:
-    """Apply every acknowledged commit to the reference in vid order;
-    False when the vids are not exactly ``n0, n0 + 1, ...``.  A commit
-    whose vid or parent is out of that order is not applied."""
+    """Apply every acknowledged commit, merges and single-parent commits
+    alike, to the reference in vid order; False when the vids are not
+    exactly ``n0, n0 + 1, ...``.  A commit whose vid or parents are out of
+    that order, or whose kept records the reference cannot place, is not
+    applied."""
     ok = True
-    for i, (vid, parent, widx, k) in enumerate(sorted(acks)):
-        keep, new_rows = writers[widx].edits[k]
-        if vid != n0 + i or not 0 <= parent < len(ref.rlists):
+    for i, (vid, parents, widx, k) in enumerate(sorted(acks)):
+        e = writers[widx].edits[k]
+        if vid != n0 + i or not all(0 <= p < len(ref.rlists)
+                                    for p in parents):
             ok = False
             continue
-        ref.commit(parent, keep, new_rows)
+        if len(parents) == 1:
+            ref.commit(parents[0], e.keep, e.new_rows)
+            continue
+        try:
+            ref.merge(parents, e.kept, e.new_rows)
+        except (KeyError, ValueError):
+            ok = False
     return bool(ok)
 
 
 def _differs(ref, vid: int, block) -> bool:
     """True unless ``block`` is exactly version ``vid`` of the reference;
-    a vid the reference does not hold differs."""
-    if not 0 <= vid < len(ref.rlists):
+    a vid the reference does not hold, or no block, differs."""
+    if block is None or not 0 <= vid < len(ref.rlists):
         return True
     want = ref.checkout(vid)
     return not (block.shape == want.shape and np.array_equal(block, want))
@@ -85,13 +94,20 @@ def readback_sample(acks, n: int, rng) -> list[int]:
 
 
 def read_back(srv, vids: list[int]) -> list:
-    """Check the versions out through the server: [(vid, block)]."""
+    """Check the versions out through the server: [(vid, block)], the
+    block None where the server has lost the answer."""
     if not vids:
         return []
     tickets = srv.submit_many(vids)
     srv.flush()
     srv.deliver()
-    return [(v, np.array(srv.result(t))) for v, t in zip(vids, tickets)]
+    got = []
+    for v, t in zip(vids, tickets):
+        try:
+            got.append((v, np.array(srv.result(t))))
+        except KeyError:
+            got.append((v, None))
+    return got
 
 
 def wrong_readback(ref, got) -> int:
@@ -119,18 +135,23 @@ def _array(x):
 
 
 def journal_mismatches(ref, acks, writers: dict, jrecs: dict) -> int:
-    """Acknowledged commits the journal misses or holds with another
-    parent, another row count or other new rows."""
+    """Acknowledged commits the journal misses or holds with other
+    parents, another row count or other new rows.  A single-parent commit
+    is journaled under ``parent``, a merge under ``parents``, in the order
+    the writer named them (its own version first)."""
     bad = 0
-    for vid, parent, widx, k in acks:
+    for vid, parents, widx, k in acks:
         c = jrecs.get(vid)
         if c is None:
             bad += 1
             continue
-        _, new_rows = writers[widx].edits[k]
+        new_rows = writers[widx].edits[k].new_rows
         got = _array(c.get("new_rows"))
         rlist = _array(c.get("rlist"))
-        bad += not (c.get("parent") == parent and 0 <= vid < len(ref.rlists)
+        named = ([c.get("parent")] if len(parents) == 1
+                 else c.get("parents"))
+        bad += not (named is not None and list(named) == list(parents)
+                    and 0 <= vid < len(ref.rlists)
                     and rlist is not None and len(rlist) == ref.size(vid)
                     and got is not None and np.array_equal(got, new_rows))
     return int(bad)

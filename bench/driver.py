@@ -12,6 +12,16 @@ answer is there from the server's public counters (``stats.waves``,
 ``waves_delivered``, ``commit_waves``): a flush dispatches every pending
 read as one wave, and waves deliver in the order they were dispatched.
 
+A writer edits its newest acknowledged version (``reference.edit_table``)
+and commits the table, ``{"parent", "table"}``.  With ``merge_every`` m,
+its commit k is a merge when ``k % m == m - 1``: it draws a partner among
+the other writers, edits the union by record of its own and the
+partner's newest acknowledged versions (a record both hold once, value-
+equal rows of two records both), and commits ``{"parents": [own,
+partner], "table"}``.  A writer names its rows' records by identity
+(``reference.minted_ids``), kept in checkout order, so its table stays in
+the store's checkout order after a merge.
+
 Latencies are taken by the clients' own clock, from just before the submit
 call to just after ``result()`` returns.
 """
@@ -21,11 +31,11 @@ import collections
 import contextlib
 import dataclasses
 import time
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
-from .reference import Ranks, edit_table
+from .reference import Ranks, edit_table, minted_ids
 
 WRITER_PK_BASE = 1 << 30     # writers' new keys: clear of the generated pool
 WRITER_PK_SPAN = 1 << 24     # keys each writer may mint
@@ -44,6 +54,7 @@ MIX_KEYS = {
     "commit": None,           # a writer's edit: delete_frac, updates, inserts
     "warmup_commit_waves": 0,  # write waves landed in set-up
     "readback": 0,            # acknowledged commits read back, besides tips
+    "merge_every": 0,         # m > 0: writer commit k merges if k % m == m - 1
 }
 LOOPS = ("closed",)
 
@@ -66,12 +77,18 @@ def parse_mix(raw: dict) -> dict:
         raise ValueError("traffic mix: needs a reader, and writers >= 0")
     if not 0.0 <= mix["check_share"] <= 1.0:
         raise ValueError("traffic mix: check_share lies in [0, 1]")
+    merge = mix["merge_every"]
+    if isinstance(merge, bool) or not isinstance(merge, int) or merge < 0:
+        raise ValueError("traffic mix: merge_every is a whole number >= 0")
     if mix["writers"]:
         edit = mix["commit"] or {}
         if set(edit) != {"delete_frac", "updates", "inserts"}:
             raise ValueError("traffic mix: writers need a commit with "
                              "delete_frac, updates and inserts")
-    elif mix["commit"] is not None or mix["warmup_commit_waves"]:
+        if merge and mix["writers"] < 2:
+            raise ValueError("traffic mix: merge_every needs two writers "
+                             "or more")
+    elif mix["commit"] is not None or mix["warmup_commit_waves"] or merge:
         raise ValueError("traffic mix: commit settings without writers")
     return mix
 
@@ -87,17 +104,30 @@ class Reader:
     t_submit: float = 0.0
 
 
+class Edit(NamedTuple):
+    """One commit a writer drew: its base table's rows at positions
+    ``keep``, then ``new_rows``.  A merge's base is the union of its
+    parents' records, so it also names them: ``kept``, the identities
+    (``reference.minted_ids``) of the kept rows."""
+    keep: np.ndarray
+    new_rows: np.ndarray
+    kept: Optional[np.ndarray] = None
+
+
 @dataclasses.dataclass
 class Writer:
     idx: int
     rng: np.random.Generator
     table: np.ndarray            # rows of its newest acknowledged version
     parent: int                  # that version's vid
-    edits: list = dataclasses.field(default_factory=list)  # (keep, new_rows)
+    ids: np.ndarray              # identity of each row of ``table``
+    edits: list = dataclasses.field(default_factory=list)  # Edit per commit
     k: int = 0                   # commits submitted so far
     ticket: int = -1
     t_submit: float = 0.0
     pending: Optional[np.ndarray] = None   # table of the commit in flight
+    pending_ids: Optional[np.ndarray] = None  # ids of its kept rows
+    parents: tuple = ()          # the vids the commit in flight names
 
 
 @dataclasses.dataclass
@@ -107,7 +137,8 @@ class Record:
     writes: list = dataclasses.field(default_factory=list)  # (t0, t1, vid)
     samples: list = dataclasses.field(default_factory=list)  # (vid, block)
     waves: list = dataclasses.field(default_factory=list)   # (t, vids)
-    acks: list = dataclasses.field(default_factory=list)    # (vid, parent, writer, k)
+    acks: list = dataclasses.field(default_factory=list)
+    # (vid, parents, writer, k): parents is (parent,) or (own, partner)
     unsynced_acks: int = 0       # write waves acknowledged with no fsync
     commit_waves: int = 0
     commit_times: list = dataclasses.field(default_factory=list)  # (t, n)
@@ -122,8 +153,8 @@ class ClosedLoop:
 
     def __init__(self, srv, readers: list[Reader], writers: list[Writer], *,
                  newest: int, ranks: Ranks, check_share: float,
-                 commit_edit: Optional[dict], journal=None,
-                 clock: Callable[[], float] = time.perf_counter,
+                 commit_edit: Optional[dict], merge_every: int = 0,
+                 journal=None, clock: Callable[[], float] = time.perf_counter,
                  span: Callable[[str], object] = None):
         self.srv = srv
         self.readers = readers
@@ -132,6 +163,7 @@ class ClosedLoop:
         self.ranks = ranks
         self.check_share = check_share
         self.commit_edit = commit_edit
+        self.merge_every = merge_every
         self.journal = journal
         self.clock = clock
         self.span = span or (lambda name: contextlib.nullcontext())
@@ -188,26 +220,40 @@ class ClosedLoop:
         r.t_submit = self.clock()
         (r.ticket,) = self._call("bench.submit", self.srv.submit_many, [r.vid])
 
-    def _next_edit(self, w: Writer):
-        if w.k == len(w.edits):
-            e = self.commit_edit
-            keep, new_rows, _ = edit_table(
-                w.rng, w.table, delete_frac=e["delete_frac"],
-                updates=e["updates"], inserts=e["inserts"],
-                next_pk=WRITER_PK_BASE + w.idx * WRITER_PK_SPAN
-                + w.k * e["inserts"])
-            w.edits.append((keep, new_rows))
-        return w.edits[w.k]
+    def _base(self, w: Writer):
+        """The table commit ``w.k`` edits, its rows' identities, and the
+        parents it names.  A merge draws its partner from the writer's own
+        stream, uniformly among the other writers, and edits the union of
+        both newest acknowledged versions by record, in checkout order."""
+        m = self.merge_every
+        if not m or w.k % m != m - 1:
+            return w.table, w.ids, (w.parent,)
+        others = [o for o in self.writers if o is not w]
+        p = others[int(w.rng.integers(len(others)))]
+        ids, first = np.unique(np.concatenate([w.ids, p.ids]),
+                               return_index=True)
+        return (np.concatenate([w.table, p.table])[first], ids,
+                (w.parent, p.parent))
 
     def _submit_write(self, w: Writer) -> None:
-        keep, new_rows = self._next_edit(w)
-        w.pending = np.concatenate([w.table[keep], new_rows])
+        base, ids, parents = self._base(w)
+        e = self.commit_edit
+        keep, new_rows, w.pending = edit_table(
+            w.rng, base, delete_frac=e["delete_frac"], updates=e["updates"],
+            inserts=e["inserts"], next_pk=WRITER_PK_BASE
+            + w.idx * WRITER_PK_SPAN + w.k * e["inserts"])
+        w.pending_ids, w.parents = ids[keep], parents
+        w.edits.append(Edit(keep, new_rows,
+                            w.pending_ids if len(parents) > 1 else None))
         w.k += 1
         self.rec.submitted += 1
         self._pending_writes.append(w)
         w.t_submit = self.clock()
+        commit = ({"parent": w.parent} if len(parents) == 1
+                  else {"parents": list(parents)})
+        commit["table"] = w.pending
         (w.ticket,) = self._call("bench.submit_commit", self.srv.submit_commit,
-                                 [{"parent": w.parent, "table": w.pending}])
+                                 [commit])
 
     def _claim_read(self, r: Reader) -> None:
         try:
@@ -230,7 +276,9 @@ class ClosedLoop:
             return False
         t1 = self.clock()
         self.rec.writes.append((w.t_submit, t1, vid))
-        self.rec.acks.append((vid, w.parent, w.idx, w.k - 1))
+        self.rec.acks.append((vid, w.parents, w.idx, w.k - 1))
+        n_new = len(w.pending) - len(w.pending_ids)
+        w.ids = np.concatenate([w.pending_ids, minted_ids(vid, n_new)])
         w.table, w.parent, w.pending = w.pending, vid, None
         self.newest = max(self.newest, vid)
         return True

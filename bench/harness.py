@@ -35,6 +35,14 @@ from .reference import HostReference, Ranks
 WARMUP_SEED = 0          # the warm-up's reads: the same for every seed
 DRAIN_S = 60.0           # an answer may come this long after the window
 
+# The configuration keys the harness acts on.  Any other deployment key, or
+# any other server key that is set, is refused: a setting the run would
+# not act on must not be measured as if it had been.
+DEPLOYMENT_KEYS = ("kind", "n_versions", "inserts", "n_branches", "n_attrs",
+                   "update_frac", "delete_frac", "dtype", "gamma",
+                   "history_seed", "budget_frac")
+SERVER_KEYS = ("use_kernel", "pipeline", "max_wave", "deadline_s")
+
 _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                    "/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
@@ -61,6 +69,21 @@ class CompileWatch:
     def _span(self, event, start, end, **_):
         if event == _BACKEND_COMPILE:
             self.spans.append((start, end))
+
+
+def check_config(cfg: dict) -> None:
+    """Raises ValueError naming a deployment key the harness does not
+    drive, or a server key it does not drive that is set (not null).  The
+    records it generates are int32, so another ``dtype`` is refused too."""
+    d = cfg["deployment"]
+    unknown = [f"deployment.{k}" for k in d if k not in DEPLOYMENT_KEYS]
+    unknown += [f"server.{k}" for k, v in cfg["server"].items()
+                if k not in SERVER_KEYS and v is not None]
+    if d.get("dtype") != "int32":
+        unknown.append(f"deployment.dtype {d.get('dtype')!r}")
+    if unknown:
+        raise ValueError(f"configuration {cfg.get('name')!r}: the benchmark "
+                         f"does not act on {', '.join(unknown)}")
 
 
 @dataclasses.dataclass
@@ -155,10 +178,12 @@ def make_clients(dep: Deployment, mix: dict, seed: int):
                for i, s in enumerate(warm)]
     window = [np.random.default_rng(s) for s in r_ss.spawn(mix["readers"])]
     tips = dep.hist.tips
-    writers = [Writer(idx=i, rng=np.random.default_rng(s),
-                      table=dep.data[dep.hist.rlists[tips[i % len(tips)]]],
-                      parent=tips[i % len(tips)])
-               for i, s in enumerate(w_ss.spawn(mix["writers"]))]
+    writers = []
+    for i, s in enumerate(w_ss.spawn(mix["writers"])):
+        tip = tips[i % len(tips)]
+        rlist = np.asarray(dep.hist.rlists[tip], dtype=np.int64)
+        writers.append(Writer(idx=i, rng=np.random.default_rng(s),
+                              table=dep.data[rlist], parent=tip, ids=rlist))
     return readers, window, writers
 
 
@@ -182,6 +207,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
     """Run one cell once; returns the result object (see ``run.py``)."""
     import jax
     from repro.core.journal import attach_journal
+    check_config(cfg)
     clock = time.perf_counter
     dev = jax.devices()[0]
     phases = [("start", clock() - t_start)]
@@ -200,7 +226,8 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
     loop = ClosedLoop(srv, readers, writers, newest=n0 - 1,
                       ranks=Ranks(mix["ranks"], n0),
                       check_share=mix["check_share"],
-                      commit_edit=mix["commit"], journal=journal,
+                      commit_edit=mix["commit"],
+                      merge_every=mix["merge_every"], journal=journal,
                       clock=clock, span=span)
     # -- warm-up on the fixed stream, then the window's streams -------------
     loop.start()
@@ -258,15 +285,16 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
     # -- the reference -------------------------------------------------------
     ref = HostReference(dep.hist.rlists, dep.data)
     w_by_idx = {w.idx: w for w in writers}
-    order_ok = check.replay_commits(ref, acks, w_by_idx, n0)
+    commit_parts = {
+        "order": int(not check.replay_commits(ref, acks, w_by_idx, n0)),
+        "readback": check.wrong_readback(ref, got_back),
+        "journal": (check.journal_mismatches(ref, acks, w_by_idx, jrecs)
+                    if jrecs is not None else 0)}
     results = check.Checks()
     results.add("unanswered", unanswered + warm.lost + rec.lost)
     results.add("wrong_blocks", check.wrong_blocks(ref, rec.reads,
                                                    rec.samples))
-    results.add("wrong_commits", (not order_ok)
-                + check.wrong_readback(ref, got_back)
-                + (check.journal_mismatches(ref, acks, w_by_idx, jrecs)
-                   if jrecs is not None else 0))
+    results.add("wrong_commits", sum(commit_parts.values()))
     results.add("acks_without_fsync",
                 warm.unsynced_acks + rec.unsynced_acks)
 
@@ -297,10 +325,13 @@ def run_cell(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
          f"window_s={ctx.seconds} warmup_waves={len(warm.waves)} "
          f"window_waves={len(ctx.waves)} compiles_in_window={compiles} "
          f"forced_flushes={rec.forced} commit_waves={rec.commit_waves} "
-         f"acks={len(rec.acks)} samples={len(rec.samples)} "
+         f"acks={len(rec.acks)} "
+         f"merges={sum(len(a[1]) > 1 for a in rec.acks)} "
+         f"samples={len(rec.samples)} "
          f"readback={len(got_back)} phases_s="
          + ",".join(f"{k}:{v:.2f}" for k, v in phases))
     return {"ctx": ctx, "checks": results, "attempted": attempted,
             "failed": unanswered + warm.lost + rec.lost, "peak": peak,
-            "breakdown": breakdown, "device": dev, "rec": rec}
+            "breakdown": breakdown, "device": dev, "rec": rec, "acks": acks,
+            "commit_parts": commit_parts}
 

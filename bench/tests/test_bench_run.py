@@ -109,6 +109,22 @@ def test_each_fault_comes_out_not_correct(watch, tmp_path, fault, mix,
     assert checks.values[number] > 0, checks.report()
 
 
+@pytest.mark.parametrize("fault,number", [
+    (_stale, "wrong_blocks"),
+    (_lose_half, "unanswered"),
+    (_alter, "wrong_blocks"),
+    (_unapplied_commit, "wrong_commits"),
+])
+def test_each_fault_of_the_quarter_commit_cell_comes_out_not_correct(
+        watch, tmp_path, fault, number):
+    """``sci_1m_quarter.read_commit``: reads and commits over groups that
+    commit waves extend and evict."""
+    checks, _ = run(watch, tmp_path, "sci_1m_quarter", "read_commit",
+                    broken(fault))
+    assert not checks.correct
+    assert checks.values[number] > 0, checks.report()
+
+
 @pytest.mark.parametrize("guarantee,mix,numbers", [
     ("exact", "zipf_read", ["wrong_blocks"]),
     ("durable", "read_commit", ["wrong_commits", "acks_without_fsync"]),
