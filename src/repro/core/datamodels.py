@@ -83,6 +83,55 @@ def diff_against_parents(table: np.ndarray, parent_rows: np.ndarray,
     return matched, new
 
 
+def match_records(table: np.ndarray, rows: np.ndarray, rids: np.ndarray
+                  ) -> tuple[np.ndarray, np.ndarray]:
+    """Match ``table`` against the records ``rids`` (their rows ``rows``)
+    as a MULTISET: (matched rids, unmatched rows), both in table order.
+
+    Each record is used at most once.  The k-th table row of a value takes
+    the k-th lowest rid among the records of that value; table rows of a
+    value beyond its records are unmatched, and become new records.  On
+    distinct rows this is ``diff_against_parents`` exactly; where records
+    share a value (twins, as a merge of two lines can bring together) it
+    keeps every one of them, where the last-wins join collapses them.
+    Vectorized on raw-byte row keys: ONE stable sort of the records (in rid
+    order) and the table rows together, then integer work per class of
+    equal keys."""
+    table = np.asarray(table)
+    rids = np.asarray(rids, dtype=np.int64)
+    if len(rids) == 0 or len(table) == 0:
+        return np.zeros(0, np.int64), table
+    rkeys = _raw_keys(rows)
+    tkeys = _raw_keys(table)
+    if rkeys.dtype != tkeys.dtype:    # row byte-widths differ: no matches
+        return np.zeros(0, np.int64), table
+    # sorted by key, a class of equal keys is one run: its records first,
+    # lowest rid first, then its table rows in table order
+    n_r = len(rids)
+    by_rid = np.argsort(rids, kind="stable")
+    keys = np.concatenate([rkeys[by_rid], tkeys])
+    order = np.argsort(keys, kind="stable")
+    skeys = keys[order]
+    head = np.r_[True, skeys[1:] != skeys[:-1]]
+    cls = np.cumsum(head) - 1
+    start = np.flatnonzero(head)
+    is_rec = order < n_r
+    n_rec = np.bincount(cls[is_rec], minlength=len(start))
+    t_cls = cls[~is_rec]
+    # the k-th table row of a class (k from 0) takes its k-th record
+    k = np.flatnonzero(~is_rec) - start[t_cls] - n_rec[t_cls]
+    ok = k < n_rec[t_cls]
+    take = order[~is_rec][ok] - n_r             # table positions
+    rid_of = np.empty(len(table), np.int64)
+    rid_of[take] = rids[by_rid[order[start[t_cls[ok]] + k[ok]]]]
+    used = np.zeros(len(table), bool)
+    used[take] = True
+    new = table[~used]
+    if len(new) == 0:
+        new = np.zeros((0, table.shape[1]), table.dtype)
+    return rid_of[used], new
+
+
 class StorageModel:
     """Shared bookkeeping: a VersionGraph and per-version row sets."""
 

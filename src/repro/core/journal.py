@@ -348,11 +348,14 @@ def replay_into(store, records: list[JournalRecord]) -> dict:
             if store.graph.n_versions > int(p["vid0"]):
                 skipped += 1
                 continue
+            # a merge's entry names ``parents`` (in the order committed),
+            # any other commit's ``parent``: each passes through as named
             store.commit_many([
                 {"rlist": _dec(c["rlist"]),
                  "new_rows": (None if c["new_rows"] is None
                               else _dec(c["new_rows"])),
-                 "parent": c["parent"],
+                 **({"parents": c["parents"]} if "parents" in c
+                    else {"parent": c["parent"]}),
                  "pid": int(c["pid"])}
                 for c in p["commits"]])
             applied += 1

@@ -384,7 +384,9 @@ class RepartitionTrigger:
         built — a ``commit_version``/``commit_many`` landing between
         observations must not error the serve flush that armed the
         trigger.  Lineage (parent, edge weight, record count) comes from
-        the store's commit log (``core.partition._log_commit``); a vid
+        the store's commit log (``core.partition._log_commit``; for a
+        merge, the parent ``to_tree`` keeps, so the tree stays the
+        DAG's Appendix C.1 reduction); a vid
         missing from the log (a store rebuilt by hand) degrades to a
         parentless node with a recomputed record count.  Returns whether
         anything was added; raises only when the tree is AHEAD of the

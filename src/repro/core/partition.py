@@ -47,6 +47,9 @@ class IngestWaveReport:
                               # (the wave's delta of ``Journal.write_s``)
     refresh_s: float = 0.0    # refresh_superblocks_after_commit: touched
                               # segment rebuild, delta upload, segment_append
+    merges: int = 0           # commits naming two or more parents
+    merge_s: float = 0.0      # the merges' union and match (part of stage_s)
+    refresh_bytes: int = 0    # host->device bytes the refresh uploaded
 
 
 @dataclasses.dataclass
@@ -236,7 +239,8 @@ class PartitionedCVD:
         self.vid_to_pid = np.append(self.vid_to_pid, -1)
         self.vid_to_pid[vids] = slot
         self.epoch += 1
-        _log_commit(self, vid, parent, edge_w, len(rlist))
+        _log_commit(self, vid, parent, edge_w, len(rlist),
+                    parents=() if parent is None else (parent,))
         try:
             refresh_superblocks_after_commit(self, {slot: old_grids})
         except Exception:
@@ -258,13 +262,20 @@ class PartitionedCVD:
 
         * ``rlist`` (+ optional ``new_rows``) — the explicit form
           ``commit_version`` takes, or
-        * ``table`` — a full row table; the delta against the parent's rows
-          is extracted via the sorted-join ``diff_against_parents`` path
-          (matched rows keep their parent rids, the rest become fresh rows),
+        * ``table`` — a full row table, matched against its parents'
+          records as a multiset (``datamodels.match_records``: each record
+          used at most once, value-equal records lowest rid first); matched
+          rows keep their rids, the rest become fresh rows in table order,
 
-        plus optional ``parent`` / ``pid``.  A commit may name a parent
-        staged EARLIER IN THE SAME WAVE (its vid is ``vid0 + i``) — chains
-        ingest in one call.
+        plus optional ``pid`` and the parents: ``parent``, or ``parents``
+        for a MERGE — two or more distinct vids, matched against the union
+        of their records.  A commit may name a parent staged EARLIER IN THE
+        SAME WAVE (its vid is ``vid0 + i``) — chains ingest in one call.
+        A merge lands, unless ``pid`` says otherwise, in the partition of
+        the parent ``version_graph.to_tree`` keeps: the one sharing the
+        most records with it, the first named on a tie.  Its journal entry
+        names ``parents`` in the order given (a single-parent commit keeps
+        ``parent``, as journals have always held it).
 
         One wave does the whole batch's work once: a single bulk CSR /
         assignment / data append, ONE partition rebuild per touched
@@ -294,12 +305,13 @@ class PartitionedCVD:
     def _commit_many(self, commits: list[dict],
                      extend_superblocks: bool) -> list[int]:
         from .checkout import refresh_superblocks_after_commit
-        from .datamodels import diff_against_parents
+        from .datamodels import match_records
         from .faults import fault_point
         from .graph import intersect_size
         from .journal import _enc, get_journal
         fault_point("ingest.extract", self)
         t0 = time.perf_counter()
+        merge_s = 0.0
         with obs.span("ingest.stage", commits=len(commits)):
             vid0 = int(self.graph.n_versions)
             n0 = int(self.graph.n_records)
@@ -321,20 +333,18 @@ class PartitionedCVD:
 
             assignment = self.assignment.copy()
             rlists: list[np.ndarray] = []
-            parents: list[Optional[int]] = []
+            parents: list[tuple[int, ...]] = []   # as each commit named them
+            kept: list[Optional[int]] = []        # to_tree's parent of each
+            edge_ws: list[int] = []               # w(kept parent, commit)
             pids: list[int] = []
             new_blocks: list[Optional[np.ndarray]] = []
             for i, c in enumerate(commits):
                 vid = vid0 + i
-                parent = c.get("parent")
-                if parent is not None:
-                    parent = int(parent)
-                    if not 0 <= parent < vid:
-                        raise ValueError(
-                            f"commit #{i}: parent vid {parent} out of range "
-                            f"[0, {vid}) (earlier wave entries are allowed)")
+                named = _named_parents(i, c, vid)
+                held = [self.graph.rlist(p) if p < vid0 else rlists[p - vid0]
+                        for p in named]
                 if c.get("table") is not None:
-                    if parent is None:
+                    if not named:
                         raise ValueError(
                             f"commit #{i}: table-form commits need a parent "
                             f"to diff against")
@@ -344,10 +354,20 @@ class PartitionedCVD:
                         raise ValueError(
                             f"commit #{i}: table shape {table.shape} does not "
                             f"match the base data width {width}")
-                    p_rids = (self.graph.rlist(parent) if parent < vid0
-                              else rlists[parent - vid0])
-                    matched, new_rows = diff_against_parents(
-                        table, staged_rows(p_rids), p_rids)
+                    if len(named) == 1:
+                        matched, new_rows = match_records(
+                            table, staged_rows(held[0]), held[0])
+                    else:
+                        # a merge: the table against the union of its
+                        # parents' records
+                        tm = time.perf_counter()
+                        base = np.unique(np.concatenate(held))
+                        with obs.span("ingest.merge", commit=i,
+                                      parents=" ".join(map(str, named)),
+                                      rows=len(base)):
+                            matched, new_rows = match_records(
+                                table, staged_rows(base), base)
+                        merge_s += time.perf_counter() - tm
                     if len(new_rows) == 0:
                         new_rows = None
                     k = 0 if new_rows is None else len(new_rows)
@@ -371,9 +391,13 @@ class PartitionedCVD:
                         raise ValueError(
                             f"commit #{i}: rlist references rid "
                             f"{int(rlist[-1])} outside [0, {n_cur + k})")
+                # to_tree's rule (App. C.1): the kept parent shares the most
+                # records with the commit, the first named on a tie
+                ws = [intersect_size(h, rlist) for h in held]
+                best = int(np.argmax(ws)) if ws else -1
                 pid = c.get("pid")
                 if pid is None:
-                    pid = (int(assignment[parent]) if parent is not None
+                    pid = (int(assignment[named[best]]) if named
                            else int(assignment.max()) + 1
                            if len(assignment) else 0)
                 pid = int(pid)
@@ -382,7 +406,9 @@ class PartitionedCVD:
                     n_cur += k
                 assignment = np.append(assignment, pid)
                 rlists.append(rlist)
-                parents.append(parent)
+                parents.append(named)
+                kept.append(named[best] if named else None)
+                edge_ws.append(ws[best] if named else 0)
                 pids.append(pid)
                 new_blocks.append(new_rows)
             # -- STAGE 2: one bulk CSR append + one rebuild per touched
@@ -413,9 +439,6 @@ class PartitionedCVD:
                 else:
                     old_grids[s] = self.partitions[s].grids
                 slot_for_pid[pid] = s
-            edge_ws = [intersect_size(staged_graph.rlist(p), rlists[i])
-                       if (p := parents[i]) is not None else 0
-                       for i in range(K)]
         stage_s = time.perf_counter() - t0
         # fires at the stage->journal boundary: store AND journal are both
         # still untouched, so a plain retry re-stages from scratch
@@ -430,7 +453,7 @@ class PartitionedCVD:
                 "vid0": vid0,
                 "commits": [{
                     "vid": vid0 + i,
-                    "parent": parents[i],
+                    **_journal_parents(parents[i]),
                     "pid": pids[i],
                     "rlist": _enc(rlists[i]),
                     "new_rows": (None if new_blocks[i] is None
@@ -456,20 +479,24 @@ class PartitionedCVD:
             self.vid_to_pid[part.vids] = s
         self.epoch += 1
         for i in range(K):
-            _log_commit(self, vid0 + i, parents[i], edge_ws[i],
-                        int(counts[i]))
+            _log_commit(self, vid0 + i, kept[i], edge_ws[i],
+                        int(counts[i]), parents=parents[i])
         t0 = time.perf_counter()
+        refresh_bytes = 0
         with obs.span("ingest.refresh"):
             try:
-                refresh_superblocks_after_commit(
-                    self, old_grids, extend=extend_superblocks)
+                refresh_bytes = refresh_superblocks_after_commit(
+                    self, old_grids,
+                    extend=extend_superblocks)["bytes_uploaded"]
             except Exception:
                 logger.warning("post-ingest superblock refresh failed; "
                                "stale device copies will lapse on next "
                                "access", exc_info=True)
         self.last_ingest = IngestWaveReport(
             commits=K, stage_s=stage_s, journal_s=journal_s,
-            refresh_s=time.perf_counter() - t0)
+            refresh_s=time.perf_counter() - t0,
+            merges=sum(len(p) > 1 for p in parents), merge_s=merge_s,
+            refresh_bytes=int(refresh_bytes))
         return list(range(vid0, vid0 + K))
 
     def apply_migration(self, plan: "MigrationPlan") -> None:
@@ -620,18 +647,56 @@ def build_partition(graph: BipartiteGraph, data: np.ndarray, pid: int,
                      vid_to_slot={int(v): i for i, v in enumerate(vids)})
 
 
+def _named_parents(i: int, c: dict, vid: int) -> tuple[int, ...]:
+    """The parents commit #``i`` (vid ``vid``) names: ``parent`` alone, or
+    ``parents``, two or more distinct vids of a merge in the order given;
+    each below ``vid``, so earlier commits of the same wave may be named.
+    A malformed naming is a ValueError, raised while staging."""
+    parent, merge = c.get("parent"), c.get("parents")
+    if merge is None:
+        named = () if parent is None else (int(parent),)
+    elif parent is not None:
+        raise ValueError(f"commit #{i}: name a parent or parents, not both")
+    else:
+        named = tuple(int(p) for p in merge)
+        if len(named) < 2 or len(set(named)) < len(named):
+            raise ValueError(
+                f"commit #{i}: a merge names two or more distinct parents, "
+                f"got {list(named)}")
+    for p in named:
+        if not 0 <= p < vid:
+            raise ValueError(
+                f"commit #{i}: parent vid {p} out of range "
+                f"[0, {vid}) (earlier wave entries are allowed)")
+    return named
+
+
+def _journal_parents(named: tuple[int, ...]) -> dict:
+    """A ``commit.batch`` entry's parents: ``parent`` (a vid or None) for a
+    commit of at most one, as journals have always held it; ``parents``,
+    in the order named, for a merge."""
+    if len(named) > 1:
+        return {"parents": list(named)}
+    return {"parent": named[0] if named else None}
+
+
 def _log_commit(store: PartitionedCVD, vid: int, parent: Optional[int],
-                edge_w: int, size: int) -> None:
+                edge_w: int, size: int, *, parents: tuple = ()) -> None:
     """Record commit lineage on the store — ``vid -> (parent, w, |rlist|)``
-    — so late observers (``online.RepartitionTrigger`` resyncing its
-    weighted tree after commits landed between observations) can extend
-    their state without recomputing record intersects."""
-    try:
-        log = store._commit_log
-    except AttributeError:
+    in ``_commit_log``, the parent being the one ``to_tree`` keeps — so
+    late observers (``online.RepartitionTrigger`` resyncing its weighted
+    tree after commits landed between observations) can extend their
+    state without recomputing record intersects.  Every parent the commit
+    named, a merge's too, goes to ``_commit_parents`` beside it."""
+    log = getattr(store, "_commit_log", None)
+    if log is None:
         log = store._commit_log = {}
+    named = getattr(store, "_commit_parents", None)
+    if named is None:
+        named = store._commit_parents = {}
     log[int(vid)] = (-1 if parent is None else int(parent),
                      int(edge_w), int(size))
+    named[int(vid)] = tuple(int(p) for p in parents)
 
 
 # ------------------------------------------------------------- migration --

@@ -38,6 +38,7 @@ SPAN_NAMES = (
     "checkout.d2h",           # copy one packed gather to the host and split it
     "ingest.commit_many",     # one commit wave, end to end
     "ingest.stage",           # delta extraction, CSR/data concat, rebuilds
+    "ingest.merge",           # one merge's match against its parents' union
     "journal.append",         # encode, write and (sync) fsync one record
     "journal.fsync",          # the fsync of a synced record
     "ingest.refresh",         # post-commit superblock extension and upload

@@ -180,6 +180,7 @@ class CheckoutStats:
     commit_waves: int = 0          # landed write waves (ONE journal fsync
                                    # and ONE epoch bump each)
     commits_ingested: int = 0      # commits those waves carried
+    merges_ingested: int = 0       # of those, merges (two or more parents)
     commit_deferrals: int = 0      # write waves a lease-drain timeout
                                    # deferred (re-queued, retried at the
                                    # next flush)
@@ -201,6 +202,9 @@ class CheckoutStats:
     ingest_stage_s: float = 0.0    # commit_many STAGE 1 + 2
     journal_s: float = 0.0         # commit.batch append, encode to fsync
     refresh_s: float = 0.0         # post-commit superblock refresh
+    merge_s: float = 0.0           # merges' union and match: a part of
+                                   # ingest_stage_s, not a stage of its own
+    refresh_h2d_bytes: int = 0     # bytes the refresh uploaded
     # CHECKOUT latency, server submit to delivery (commits are not stamped
     # here): a sliding window (deque, maxlen) — unbounded growth would leak
     # on a long-running server; `requests` keeps the all-time count.
@@ -392,7 +396,9 @@ class BatchedCheckoutServer:
     def submit_commit(self, commits: Sequence[dict]) -> list[int]:
         """Queue a write wave: one WRITE TICKET per commit dict (the
         ``PartitionedCVD.commit_many`` forms — ``rlist``/``new_rows`` or
-        ``table``, plus ``parent``/``pid``), minted from the same
+        ``table``, plus ``pid`` and ``parent``, or ``parents`` for a
+        merge: two or more vids, acknowledged with all of them journaled
+        in the order given), minted from the same
         namespace as checkout tickets.  The whole pending write queue
         lands as ONE fused ingest wave at the next ``flush()`` — before
         that flush's read dispatch, so coalesced reads observe the new
@@ -722,6 +728,9 @@ class BatchedCheckoutServer:
             self.stats.ingest_stage_s += report.stage_s
             self.stats.journal_s += report.journal_s
             self.stats.refresh_s += report.refresh_s
+            self.stats.merges_ingested += report.merges
+            self.stats.merge_s += report.merge_s
+            self.stats.refresh_h2d_bytes += report.refresh_bytes
         return vids
 
     def _commit(self, commits: list) -> list[int]:

@@ -318,7 +318,9 @@ def test_a_cpu_trace_holds_the_spans_nested_under_flush(rng, tmp_path):
     try:
         srv.submit_many([1, 2, 3])
         srv.flush()                        # dispatches wave 1
-        srv.submit_commit([{"parent": 0, "rlist": store.graph.rlist(0)}])
+        srv.submit_commit([{"parent": 0, "rlist": store.graph.rlist(0)},
+                           {"parents": [1, 2],
+                            "table": store.checkout(1)}])
         srv.submit_many([0, 3])
         srv.flush()                        # lands commit wave 0, delivers
         srv.deliver()                      # wave 1, dispatches wave 2
